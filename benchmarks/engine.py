@@ -312,6 +312,8 @@ def full_run(query, fused, qcfg):
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--repeats", type=int, default=3,
                     help="pump runs per mode; best (lowest wall) kept")

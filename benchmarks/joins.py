@@ -103,6 +103,8 @@ def run_one(query: str, mode: str, qcfg: dict, duration: float,
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", default="q8,q20")
     ap.add_argument("--modes", default="ondemand,onesided,twosided")

@@ -159,6 +159,8 @@ def run_alert_oracle():
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--duration", type=float, default=6.0)
     ap.add_argument("--warmup", type=float, default=2.0)

@@ -164,6 +164,8 @@ def run_one(query: str, scenario: str, qcfg: dict, seed: int = 7):
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", default="q5,q20")
     ap.add_argument("--scenarios", default="unfailed,cold,warmed")

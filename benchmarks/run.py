@@ -30,6 +30,8 @@ def main() -> None:
     root = os.path.join(os.path.dirname(__file__), "..")
     sys.path.insert(0, os.path.join(root, "src"))
     sys.path.insert(0, root)              # `benchmarks` package itself
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import paper, roofline
     paper.FUSED = args.fused
 

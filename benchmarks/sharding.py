@@ -91,6 +91,8 @@ def run_one(query: str, n_owners: int, mode: str, duration: float,
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", default="q3,q4")
     ap.add_argument("--shards", default="1,2,4,8")

@@ -90,6 +90,8 @@ def run_one(query: str, mode: str, qcfg: dict, duration: float,
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", default="q5,q7")
     ap.add_argument("--modes", default="ondemand,arrival,deadline")

@@ -204,11 +204,11 @@ class HintFilter:
     # ------------------------------------------------------------ device twin
     def classify_batch(self, keys):
         """Batched hot/cold classification through the ``cms_sketch``
-        Pallas kernel (interpret mode on CPU).  Maintains a SEPARATE
-        counter/hash state from the host sketch — the two share
-        semantics, not hash values — and applies the same aging rule
-        (halve every ``aging_interval`` updates).  Returns a bool[B]
-        hot mask."""
+        Pallas kernel (compiled on a TPU, interpreted elsewhere).
+        Maintains a SEPARATE counter/hash state from the host sketch —
+        the two share semantics, not hash values — and applies the same
+        aging rule (halve every ``aging_interval`` updates).  Returns a
+        bool[B] hot mask."""
         import numpy as np
         from repro.kernels.cms_sketch.ops import cms_update_and_classify
         cms = self.cms
@@ -226,8 +226,7 @@ class HintFilter:
         keys = np.asarray(keys, dtype=np.int32)
         new_counters, hot = cms_update_and_classify(
             keys, dev["counters"], dev["a"], dev["b"],
-            threshold=cms.threshold, max_count=cms.max_count,
-            interpret=True)
+            threshold=cms.threshold, max_count=cms.max_count)
         counters = np.asarray(new_counters)
         dev["since_aging"] += int(keys.shape[0])
         if dev["since_aging"] >= cms.aging_interval:

@@ -46,12 +46,10 @@ def init(n_buckets: int, ways: int, d: int,
         dirty=jnp.zeros((n_buckets, ways), bool))
 
 
-def lookup(state: TACState, qkeys: jax.Array, now_ts: jax.Array,
-           interpret: bool = True
+def lookup(state: TACState, qkeys: jax.Array, now_ts: jax.Array
            ) -> Tuple[jax.Array, jax.Array, TACState]:
     """Batched probe+gather; refreshes timestamps of hits (max with now)."""
-    vals, hit, way = tac_probe(qkeys, state.keys, state.vals,
-                               interpret=interpret)
+    vals, hit, way = tac_probe(qkeys, state.keys, state.vals)
     b = bucket_of(qkeys, state.keys.shape[0])
     safe_way = jnp.maximum(way, 0)
     cur = state.ts[b, safe_way]
@@ -62,7 +60,7 @@ def lookup(state: TACState, qkeys: jax.Array, now_ts: jax.Array,
 
 def renew(state: TACState, keys: jax.Array, hint_ts: jax.Array) -> TACState:
     """Bump predicted relevance of cached keys (hint for a cached entry)."""
-    _, hit, way = tac_probe(keys, state.keys, state.vals, interpret=True)
+    _, hit, way = tac_probe(keys, state.keys, state.vals)
     b = bucket_of(keys, state.keys.shape[0])
     safe = jnp.maximum(way, 0)
     cur = state.ts[b, safe]
@@ -197,16 +195,14 @@ def shard_mask(keys: jax.Array, shard_id: int, n_shards: int) -> jax.Array:
 
 
 def probe_owned(state: TACState, keys: jax.Array, shard_id: int,
-                n_shards: int, interpret: bool = True
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                n_shards: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Shard-local probe: foreign keys (misrouted in the shard plane) are
     forced to miss so a stray probe can never refresh another shard's
     entries.  Returns (vals, hit, owned) — callers count ``~owned`` lanes
     as misroutes, not misses."""
     keys = jnp.asarray(keys, jnp.int32)
     owned = shard_mask(keys, shard_id, n_shards)
-    vals, hit, _ = tac_probe(keys, state.keys, state.vals,
-                             interpret=interpret)
+    vals, hit, _ = tac_probe(keys, state.keys, state.vals)
     return vals, hit.astype(bool) & owned, owned
 
 
@@ -362,7 +358,7 @@ def set_dirty(state: TACState, keys: jax.Array,
     idempotent under duplicate indices: ``.at[].set`` with a stale value
     could clobber a hit lane's update (unspecified duplicate order) —
     ``.at[].max``/``.at[].min`` with a neutral element cannot."""
-    _, hit, way = tac_probe(keys, state.keys, state.vals, interpret=True)
+    _, hit, way = tac_probe(keys, state.keys, state.vals)
     hit = hit.astype(bool)
     b = bucket_of(keys, state.keys.shape[0])
     safe = jnp.maximum(way, 0)
@@ -389,20 +385,21 @@ def set_dirty(state: TACState, keys: jax.Array,
 # agree by construction.
 
 # The fused entry points below are LATENCY-critical: one call per engine
-# batch, plus one per single-key cold-path op.  ``interpret=True`` means
-# no real TPU backend is in play — and the pallas interpreter emulates
-# the kernel grid step by step, orders of magnitude slower than the XLA
-# program the same jit would otherwise produce.  So in interpret mode
-# the probe/gather/scatter run as the kernels' pure-jnp reference ops
-# fused into the surrounding jitted program (bit-identical semantics;
-# tests/test_kernels.py holds kernel and reference to each other), and
-# the pallas kernels serve the ``interpret=False`` accelerator path.
+# batch, plus one per single-key cold-path op.  Which code runs their
+# probe, gather and scatter is decided when the jitted program is LOWERED
+# (``jax.lax.platform_dependent``), never by a caller:
+#
+#   * lowered for a TPU, the Pallas kernels run compiled
+#     (``tac_probe_gather``, ``page_gather_kernel``,
+#     ``page_scatter_kernel``) — no interpreter, no reference op;
+#   * lowered for any other platform (the CPU test suite), the kernels'
+#     pure-jnp reference ops run fused into the surrounding XLA program.
+#     The Pallas interpreter would emulate the kernel grid step by step,
+#     orders of magnitude slower, and the semantics are bit-identical:
+#     tests/test_kernels.py holds kernel and reference to each other.
 
-def _probe_gather(keys, state: "TACState", pages, interpret: bool):
-    if not interpret:
-        return tac_probe_gather(keys, state.keys, state.vals, pages,
-                                interpret=False)
-    n_buckets, ways = state.keys.shape
+def _probe_gather_ref(keys, dir_keys, dir_vals, pages):
+    n_buckets, ways = dir_keys.shape
     trash = pages.shape[0] - 1
     if n_buckets == 1:
         # fully-associative fast path (every FusedPlane directory):
@@ -410,7 +407,7 @@ def _probe_gather(keys, state: "TACState", pages, interpret: bool):
         # first-match resolves via iota-min — argmax lowers ~3x slower
         # on the CPU backend, and the directory-vals gather the generic
         # probe does is dead weight here (payloads live in the pool)
-        match = state.keys[0][None, :] == keys[:, None]
+        match = dir_keys[0][None, :] == keys[:, None]
         iota = jnp.arange(ways, dtype=jnp.int32)
         way = jnp.min(jnp.where(match, iota, ways), axis=1)
         hit = way < ways
@@ -419,22 +416,26 @@ def _probe_gather(keys, state: "TACState", pages, interpret: bool):
     else:
         buckets = bucket_of(keys, n_buckets)
         _, hiti, way = tac_probe_ref(keys.astype(jnp.int32), buckets,
-                                     state.keys, state.vals)
+                                     dir_keys, dir_vals)
         hit = hiti.astype(bool)
         slots = jnp.where(hit, buckets * ways + jnp.maximum(way, 0),
                           trash).astype(jnp.int32)
     return page_gather_ref(slots, pages), hit, slots
 
 
-def _gather(slots, pages, interpret: bool):
-    if not interpret:
-        return page_gather_kernel(slots, pages, interpret=False)
-    return page_gather_ref(slots, pages)
+def _probe_gather(keys, state: "TACState", pages):
+    return jax.lax.platform_dependent(
+        keys, state.keys, state.vals, pages,
+        tpu=lambda k, dk, dv, p: tac_probe_gather(k, dk, p),
+        default=_probe_gather_ref)
 
 
-def _scatter(slots, blocks, pages, interpret: bool):
-    if not interpret:
-        return page_scatter_kernel(slots, blocks, pages, interpret=False)
+def _gather(slots, pages):
+    return jax.lax.platform_dependent(
+        slots, pages, tpu=page_gather_kernel, default=page_gather_ref)
+
+
+def _scatter_ref(slots, blocks, pages):
     # last-write-wins matching the kernel's grid order: non-final writes
     # to a duplicated slot redirect to the scratch row (the pool's last
     # row, which fused callers keep zeroed / overwrite before reading)
@@ -444,6 +445,12 @@ def _scatter(slots, blocks, pages, interpret: bool):
         (idx[None, :] > idx[:, None])
     eff = jnp.where(later.any(axis=1), pages.shape[0] - 1, slots)
     return pages.at[eff].set(blocks)
+
+
+def _scatter(slots, blocks, pages):
+    return jax.lax.platform_dependent(
+        slots, blocks, pages, tpu=page_scatter_kernel,
+        default=_scatter_ref)
 
 
 class FusedStep(NamedTuple):
@@ -457,11 +464,10 @@ class FusedStep(NamedTuple):
     tallies: jax.Array    # [2] int32  (hits, misses) over valid lanes
 
 
-@partial(jax.jit, static_argnames=("kind", "interpret"))
+@partial(jax.jit, static_argnames=("kind",))
 def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
                ts: jax.Array, weights: jax.Array, fire: jax.Array,
-               valid: jax.Array, *, kind: str = "sum",
-               interpret: bool = True) -> FusedStep:
+               valid: jax.Array, *, kind: str = "sum") -> FusedStep:
     """One fused batch over the resident working set.
 
     ``kind`` picks the operator compute (static — one compiled program
@@ -483,7 +489,7 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
     B = keys.shape[0]
     n_buckets, ways = state.keys.shape
     trash = pages.shape[0] - 1
-    rows, hit, slots = _probe_gather(keys, state, pages, interpret)
+    rows, hit, slots = _probe_gather(keys, state, pages)
     hit = hit & valid
     slots = jnp.where(hit, slots, trash)
     safe_b = jnp.where(hit, slots // ways, 0)
@@ -506,8 +512,11 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
                       -jnp.inf).max(axis=1)
         new_v = jnp.maximum(jnp.where(f[:, None], g, -jnp.inf), m)
     else:                                      # sum (count = sum of ones)
-        new_v = jnp.where(f[:, None], g, 0.0) + \
-            M.astype(weights.dtype) @ weights
+        # HIGHEST: the TPU's default matmul precision rounds f32 operands
+        # to bf16, exact only for unit weights
+        new_v = jnp.where(f[:, None], g, 0.0) + jnp.matmul(
+            M.astype(weights.dtype), weights,
+            precision=jax.lax.Precision.HIGHEST)
     present = f | hasupd
     new_v = jnp.where(present[:, None], new_v, 0.0)
     dirty = state.dirty
@@ -516,7 +525,7 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
             [present[:, None].astype(pages.dtype),
              new_v.astype(pages.dtype)], axis=1)[:, None, :]
         wslots = jnp.where(upd, slots, trash)
-        pages = _scatter(wslots, blocks, pages, interpret)
+        pages = _scatter(wslots, blocks, pages)
         # the scratch row must stay "absent" for future miss gathers
         pages = pages.at[trash].set(0.0)
         d_int = state.dirty.astype(jnp.int32).at[safe_b, safe_w].max(
@@ -528,11 +537,10 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
                      hit, slots, new_v, present, tallies)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def fused_admit(state: TACState, pages: jax.Array, slots: jax.Array,
                 keys: jax.Array, ts: jax.Array, rows: jax.Array,
-                present: jax.Array, dirty: jax.Array, *,
-                interpret: bool = True):
+                present: jax.Array, dirty: jax.Array):
     """Admit at HOST-CHOSEN slots (the shadow directory resolved victims
     and free slots; a slot may repeat only as an IDENTICAL padding
     duplicate of an earlier lane — chunked flushes pad to fixed jit
@@ -542,11 +550,11 @@ def fused_admit(state: TACState, pages: jax.Array, slots: jax.Array,
     directory.  Returns ``(state, pages, victim_rows [B, 1, V+1])``."""
     n_buckets, ways = state.keys.shape
     b, w = slots // ways, slots % ways
-    victim_rows = _gather(slots, pages, interpret)
+    victim_rows = _gather(slots, pages)
     blocks = jnp.concatenate(
         [present[:, None].astype(pages.dtype),
          rows.astype(pages.dtype)], axis=1)[:, None, :]
-    new_pages = _scatter(slots, blocks, pages, interpret)
+    new_pages = _scatter(slots, blocks, pages)
     # duplicate pads spill their non-final writes into the scratch row;
     # it must read as "absent" for future miss/padding gathers
     new_pages = new_pages.at[-1].set(0.0)
@@ -578,8 +586,7 @@ def drop_slots(state: TACState, slots: jax.Array,
     return state._replace(keys=keys, ts=ts, dirty=d_int > 0)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def gather_rows(pages: jax.Array, slots: jax.Array, *,
-                interpret: bool = True) -> jax.Array:
+@jax.jit
+def gather_rows(pages: jax.Array, slots: jax.Array) -> jax.Array:
     """Pull payload rows at flat slots (single-key adapter reads)."""
-    return _gather(slots, pages, interpret)
+    return _gather(slots, pages)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.cms_sketch.cms_sketch import cms_update_kernel
+from repro.kernels.dispatch import kernel_call
 
 
 def columns_for(keys: jax.Array, a: jax.Array, b: jax.Array,
@@ -25,15 +26,14 @@ def columns_for(keys: jax.Array, a: jax.Array, b: jax.Array,
     return (h % jnp.uint32(width)).astype(jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("threshold", "max_count", "interpret"))
+@partial(jax.jit, static_argnames=("threshold", "max_count"))
 def cms_update_and_classify(keys, counters, a, b, *, threshold: int = 20,
-                            max_count: int = 255, interpret: bool = True):
+                            max_count: int = 255):
     """Batched equivalent of CountMinFilter.update_and_classify (no aging;
     the caller right-shifts ``counters`` every aging interval).
     Returns (new_counters, hot [B] bool)."""
     cols = columns_for(keys, a, b, counters.shape[1])
-    new_counters, est = cms_update_kernel(cols, counters,
-                                          max_count=max_count,
-                                          interpret=interpret)
+    new_counters, est = kernel_call(cms_update_kernel, cols, counters,
+                                    max_count=max_count)
     hot = (est >= threshold).all(axis=0)
     return new_counters, hot

@@ -1,24 +1,22 @@
 """jit'd wrappers: slot-indirect page gather / scatter."""
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import kernel_call
 from repro.kernels.page_gather.page_gather import (page_gather_kernel,
                                                    page_scatter_kernel)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def page_gather(slots, pages, *, interpret: bool = True):
+@jax.jit
+def page_gather(slots, pages):
     """slots [N]; pages [n_slots, page, d] -> [N, page, d]."""
-    return page_gather_kernel(slots.astype(jnp.int32), pages,
-                              interpret=interpret)
+    return kernel_call(page_gather_kernel, slots.astype(jnp.int32), pages)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def page_scatter(slots, blocks, pages, *, interpret: bool = True):
+@jax.jit
+def page_scatter(slots, blocks, pages):
     """pages[slots[i]] = blocks[i]; returns the updated pool."""
-    return page_scatter_kernel(slots.astype(jnp.int32), blocks, pages,
-                               interpret=interpret)
+    return kernel_call(page_scatter_kernel, slots.astype(jnp.int32), blocks,
+                       pages)

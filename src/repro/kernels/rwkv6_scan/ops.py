@@ -5,10 +5,10 @@ from functools import partial
 
 import jax
 
+from repro.kernels.dispatch import kernel_call
 from repro.kernels.rwkv6_scan.rwkv6_scan import rwkv6_scan_kernel
 
 
-@partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
-    return rwkv6_scan_kernel(r, k, v, w, u, chunk=chunk,
-                             interpret=interpret)
+@partial(jax.jit, static_argnames=("chunk",))
+def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64):
+    return kernel_call(rwkv6_scan_kernel, r, k, v, w, u, chunk=chunk)

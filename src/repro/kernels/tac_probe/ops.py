@@ -1,11 +1,10 @@
-"""jit'd wrapper: hash, probe, gather."""
+"""jit'd wrappers: hash, probe, gather."""
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import kernel_call
 from repro.kernels.page_gather.page_gather import page_gather_kernel
 from repro.kernels.tac_probe.tac_probe import tac_probe_kernel
 
@@ -17,16 +16,33 @@ def bucket_of(keys: jax.Array, n_buckets: int) -> jax.Array:
     return (h % jnp.uint32(n_buckets)).astype(jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def tac_probe(qkeys, bucket_keys, bucket_vals, *, interpret: bool = True):
+def _probe(qkeys, bucket_keys):
+    """(bucket [B], way [B], -1 = miss).  A fully-associative directory
+    is probed as its one shared row; otherwise each query's bucket row
+    is gathered first."""
+    qkeys = qkeys.astype(jnp.int32)
     buckets = bucket_of(qkeys, bucket_keys.shape[0])
-    return tac_probe_kernel(qkeys.astype(jnp.int32), buckets,
-                            bucket_keys, bucket_vals, interpret=interpret)
+    rows = bucket_keys if bucket_keys.shape[0] == 1 \
+        else bucket_keys[buckets]
+    return buckets, kernel_call(tac_probe_kernel, qkeys, rows)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def tac_probe_counted(qkeys, bucket_keys, bucket_vals, *,
-                      interpret: bool = True):
+def _values(bucket_vals, buckets, way):
+    """Value row of each hit's slot, zeros for misses."""
+    vals = bucket_vals[buckets, jnp.maximum(way, 0)]
+    return jnp.where((way >= 0)[:, None], vals, jnp.zeros_like(vals))
+
+
+@jax.jit
+def tac_probe(qkeys, bucket_keys, bucket_vals):
+    """Returns (values [B, D], hit [B] int32, way [B] int32, -1 = miss)."""
+    buckets, way = _probe(qkeys, bucket_keys)
+    return _values(bucket_vals, buckets, way), \
+        (way >= 0).astype(jnp.int32), way
+
+
+@jax.jit
+def tac_probe_counted(qkeys, bucket_keys, bucket_vals):
     """Probe + device-side tallies for the observability plane
     (DESIGN.md §12): returns ``(values, hit, way, counts)`` where
     ``counts`` is an int32 ``[2]`` vector of (n_hit, n_conflict) reduced
@@ -34,19 +50,16 @@ def tac_probe_counted(qkeys, bucket_keys, bucket_vals, *,
     already full, i.e. admitting the key would evict.  One device->host
     transfer surfaces both tallies instead of a host-side scan of the
     per-query hit vector."""
-    buckets = bucket_of(qkeys, bucket_keys.shape[0])
-    vals, hit, way = tac_probe_kernel(qkeys.astype(jnp.int32), buckets,
-                                      bucket_keys, bucket_vals,
-                                      interpret=interpret)
+    buckets, way = _probe(qkeys, bucket_keys)
+    hit = (way >= 0).astype(jnp.int32)
     full = jnp.all(bucket_keys[buckets] != -1, axis=1)
     miss = hit == 0
     counts = jnp.stack([hit.sum(), (miss & full).sum()]).astype(jnp.int32)
-    return vals, hit, way, counts
+    return _values(bucket_vals, buckets, way), hit, way, counts
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def tac_probe_gather(qkeys, bucket_keys, bucket_vals, pages, *,
-                     interpret: bool = True):
+@jax.jit
+def tac_probe_gather(qkeys, bucket_keys, pages):
     """Composed probe -> page gather (DESIGN.md §14): the directory probe
     and the payload pull run in ONE traced program instead of two island
     launches — the probe's (bucket, way) resolves to a flat slot id that
@@ -57,14 +70,11 @@ def tac_probe_gather(qkeys, bucket_keys, bucket_vals, pages, *,
     "absent" without any host-side masking.  Returns
     ``(rows [B, page, d], hit [B] bool, slots [B] int32 flat)``.
     """
-    n_buckets, ways = bucket_keys.shape
-    buckets = bucket_of(qkeys, n_buckets)
-    _, hit, way = tac_probe_kernel(qkeys.astype(jnp.int32), buckets,
-                                   bucket_keys, bucket_vals,
-                                   interpret=interpret)
-    hit = hit.astype(bool)
+    ways = bucket_keys.shape[1]
+    buckets, way = _probe(qkeys, bucket_keys)
+    hit = way >= 0
     trash = pages.shape[0] - 1
     slots = jnp.where(hit, buckets * ways + jnp.maximum(way, 0),
                       trash).astype(jnp.int32)
-    rows = page_gather_kernel(slots, pages, interpret=interpret)
+    rows = kernel_call(page_gather_kernel, slots, pages)
     return rows, hit, slots

@@ -1,11 +1,20 @@
-"""Batched TAC probe+gather as a Pallas TPU kernel.
+"""Batched TAC directory probe as a Pallas TPU kernel.
 
-The device-resident Timestamp-Aware Cache stores state rows in fixed slots
-organised as (n_buckets x ways); a batch of state-access keys is probed in
-one kernel launch: each grid step loads ONE bucket (ways keys + the ways x D
-value block) into VMEM via a scalar-prefetched bucket index, compares the
-ways keys on the VPU, and emits (value_row, hit, way).  This is the
-serving-side analogue of the paper's hash-map + gather hot path.
+The device-resident Timestamp-Aware Cache stores its directory as
+(n_buckets x ways) key slots.  A batch of B state-access keys is probed
+in one launch against directory ROWS: either the one shared row of a
+fully-associative directory (``[1, ways]``, every ``FusedPlane``) or one
+row per query, the query's hashed bucket gathered by the caller
+(``[B, ways]``, the set-associative serving arena).
+
+The grid walks the ways axis in lane-aligned tiles, so a row of 2^20
+ways never has to fit in VMEM: each step compares the whole query
+column ``[B, 1]`` against one ``[rows, tile]`` block on the VPU and
+folds the tile's first matching way into a resident ``[B, 1]``
+accumulator with a min — the earliest match across tiles wins, exactly
+the reference's ``argmax`` over the full row.  Every block is either
+lane-aligned or spans its whole array dimension, which is what the TPU
+compiler's (8, 128) tiling rule asks of a BlockSpec.
 """
 from __future__ import annotations
 
@@ -16,56 +25,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(buckets_ref, qkeys_ref, bkeys_ref, bvals_ref,
-            out_ref, hit_ref, way_ref, *, ways: int, D: int):
-    b = pl.program_id(0)
-    qk = qkeys_ref[b]
-    keys = bkeys_ref[0]                                  # [ways]
-    match = keys == qk                                   # [ways] bool
-    hit = jnp.any(match)
-    way = jnp.argmax(match)                              # first match
-    vals = bvals_ref[0]                                  # [ways, D]
-    sel = jnp.where(match[:, None], vals.astype(jnp.float32), 0.0)
-    row = sel.sum(axis=0)                                # matched row or 0
-    out_ref[0] = row.astype(out_ref.dtype)
-    hit_ref[0] = hit.astype(jnp.int32)
-    way_ref[0] = jnp.where(hit, way, -1).astype(jnp.int32)
+TILE = 2048                      # ways per grid step (a multiple of 128)
 
 
-def tac_probe_kernel(qkeys: jax.Array, buckets: jax.Array,
-                     bucket_keys: jax.Array, bucket_vals: jax.Array, *,
-                     interpret: bool = False):
-    """qkeys [B] int32; buckets [B] int32 (hash(qkey) % n_buckets, computed
-    by the caller); bucket_keys [n_buckets, ways] int32 (-1 = empty);
-    bucket_vals [n_buckets, ways, D].  Returns (values [B, D], hit [B],
-    way [B])."""
+def _kernel(q_ref, keys_ref, way_ref, *, tile: int, ways: int):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _():
+        way_ref[...] = jnp.full(way_ref.shape, ways, jnp.int32)
+
+    match = keys_ref[...] == q_ref[...]                  # [B, tile]
+    idx = jax.lax.broadcasted_iota(jnp.int32, match.shape, 1) + j * tile
+    # a ragged last tile reads past the row: mask those lanes out
+    cand = jnp.where(match & (idx < ways), idx, ways)
+    way_ref[...] = jnp.minimum(way_ref[...],
+                               jnp.min(cand, axis=1, keepdims=True))
+
+
+def tac_probe_kernel(qkeys: jax.Array, rows: jax.Array, *,
+                     interpret: bool = False) -> jax.Array:
+    """qkeys [B] int32; rows [1, ways] (shared) or [B, ways] (per query)
+    int32 directory keys, -1 = empty.  Returns way [B] int32: the first
+    way whose key equals the query, -1 on a miss."""
     B = qkeys.shape[0]
-    n_buckets, ways = bucket_keys.shape
-    D = bucket_vals.shape[-1]
-
-    kern = functools.partial(_kernel, ways=ways, D=D)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, ways), lambda b, bk, qk: (bk[b], 0)),
-            pl.BlockSpec((1, ways, D), lambda b, bk, qk: (bk[b], 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, D), lambda b, bk, qk: (b, 0)),
-            pl.BlockSpec((1,), lambda b, bk, qk: (b,)),
-            pl.BlockSpec((1,), lambda b, bk, qk: (b,)),
-        ],
-        scratch_shapes=[],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, D), bucket_vals.dtype),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
+    n_rows, ways = rows.shape
+    tile = ways if ways <= TILE else TILE
+    way = pl.pallas_call(
+        functools.partial(_kernel, tile=tile, ways=ways),
+        grid=(pl.cdiv(ways, tile),),
+        in_specs=[pl.BlockSpec((B, 1), lambda j: (0, 0)),
+                  pl.BlockSpec((n_rows, tile), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((B, 1), lambda j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(buckets, qkeys, bucket_keys, bucket_vals)
+    )(qkeys.astype(jnp.int32).reshape(B, 1), rows)[:, 0]
+    return jnp.where(way < ways, way, -1)

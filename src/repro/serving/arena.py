@@ -42,12 +42,10 @@ class PagedStateArena:
     """
 
     def __init__(self, n_buckets: int, ways: int,
-                 pools: Dict[str, Tuple[Tuple[int, int], Any]],
-                 interpret: bool = True):
+                 pools: Dict[str, Tuple[Tuple[int, int], Any]]):
         self.n_buckets = n_buckets
         self.ways = ways
         self.n_slots = n_buckets * ways
-        self.interpret = interpret
         self.tac = tac_jax.init(n_buckets, ways, 1)
         self.pools: Dict[str, jax.Array] = {
             name: jnp.zeros((self.n_slots, *shape), dtype)
@@ -85,11 +83,9 @@ class PagedStateArena:
             # counted variant: hit/conflict tallies reduced ON DEVICE in
             # the same launch feed the registry (DESIGN.md §12)
             _, hit_d, way, tallies = tac_probe_counted(
-                keys, self.tac.keys, self.tac.vals,
-                interpret=self.interpret)
+                keys, self.tac.keys, self.tac.vals)
         else:
-            _, hit_d, way = tac_probe(keys, self.tac.keys, self.tac.vals,
-                                      interpret=self.interpret)
+            _, hit_d, way = tac_probe(keys, self.tac.keys, self.tac.vals)
         bucket_d = bucket_of(keys, self.n_buckets)
         if now_ts is not None:                # access: refresh hit ts
             safe = jnp.maximum(way, 0)
@@ -158,8 +154,7 @@ class PagedStateArena:
         # them (rows where evicted_keys == -1 are garbage; callers filter).
         # Only DIRTY victims are ever written back, so all-clean eviction
         # rounds skip the gather entirely
-        evicted_blocks = {name: page_gather(jnp.asarray(slots), pool,
-                                            interpret=self.interpret)
+        evicted_blocks = {name: page_gather(jnp.asarray(slots), pool)
                           for name, pool in self.pools.items()} \
             if bool(((ev_k >= 0) & ev_d).any()) else {}
         self.admits += len(slots)
@@ -176,14 +171,13 @@ class PagedStateArena:
             return
         for name, blk in blocks.items():
             self.pools[name] = page_scatter(slots, blk.astype(
-                self.pools[name].dtype), self.pools[name],
-                interpret=self.interpret)
+                self.pools[name].dtype), self.pools[name])
         self.staged_pages += int(slots.shape[0])
 
     def gather(self, slots: jax.Array) -> Dict[str, jax.Array]:
         """Batched read of N physical pages from every pool."""
         slots = jnp.asarray(slots, jnp.int32)
-        return {name: page_gather(slots, pool, interpret=self.interpret)
+        return {name: page_gather(slots, pool)
                 for name, pool in self.pools.items()}
 
     # ------------------------------------------------------------- migration

@@ -1155,8 +1155,16 @@ class StatefulOp(Operator):
             prefetched = req.kind == "prefetch"
             timely = prefetched and req.key not in self.waiting[sub]
             ts = hint_ts if hint_ts is not None else req.hint_ts
-            cache.insert(req.key, state, ts, size=self.state_size,
-                         prefetched=timely, origin=req.origin)
+            if cache.contains(req.key):
+                # the key became resident while this fetch was in flight
+                # (served from the memtable shield after its write-back
+                # landed): the resident copy is newer than what the fetch
+                # read, so the completion only renews it, as a duplicate
+                # hint would — an insert would roll back applied updates
+                cache.renew(req.key, ts)
+            else:
+                cache.insert(req.key, state, ts, size=self.state_size,
+                             prefetched=timely, origin=req.origin)
             if prefetched:
                 self.recorder.on_stage_latency(lat)
                 if not timely:
@@ -1299,7 +1307,7 @@ class StatefulOp(Operator):
         if lane.key is tup.key or lane.key == tup.key:
             return tup
         return Tuple_(tup.ts, lane.key, tup.payload, tup.size,
-                      tup.ingest_t, trace=tup.trace)
+                      tup.ingest_t, trace=tup.trace, late=lane.late_update)
 
     def _fused_drain(self, sub: int) -> float:
         """Assemble one batch from the head run of data tuples, then run

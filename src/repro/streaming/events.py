@@ -15,6 +15,10 @@ class Tuple_:
     trace: Any = None         # sampled critical-path span (obs.trace), or
     #                           None on the unsampled fast path — not
     #                           serialized, never crosses a checkpoint
+    late: Optional[bool] = None  # window-pane access: was its window
+    #                           already fired when the tuple was assigned
+    #                           to it (its place in the input relative to
+    #                           the watermark)?  None = decide when applied
 
 
 class WindowKey(NamedTuple):

@@ -158,6 +158,9 @@ class WindowedStatefulOp(StatefulOp):
         # destination watermarks straddle its end.
         self.windows: List[Dict[int, dict]] = \
             [dict() for _ in range(parallelism)]
+        # panes past the lateness horizon whose purge waits for tuples
+        # parked on their state fetch (``on_watermark``), per subtask
+        self._purge_due: List[set] = [set() for _ in range(parallelism)]
         self.fires = 0
         self.fires_lost = 0
         self.late_dropped = 0
@@ -193,7 +196,7 @@ class WindowedStatefulOp(StatefulOp):
             n += 1
             svc += super()._on_data(sub, Tuple_(
                 tup.ts, WindowKey(tup.key, wid), tup.payload, tup.size,
-                tup.ingest_t, trace=tup.trace))
+                tup.ingest_t, trace=tup.trace, late=meta["fired"]))
         if not n:
             self._trace_absorbed(tup.trace)  # dropped before any pane
         return svc if n else 5e-7
@@ -214,10 +217,16 @@ class WindowedStatefulOp(StatefulOp):
                 self._purge_pane(sub, wk)
             return self.service_time
         meta = self.windows[sub].get(wk.wid)
-        if meta is not None and meta["fired"] and self.late_policy != \
-                "update":
+        # lateness is the tuple's place in the input relative to the
+        # watermark, fixed when it was assigned to the pane: one that
+        # parked on a fetch across the fire is still on time (the FIRE
+        # parks behind it, so the fired result counts it).  Re-delivered
+        # pane accesses carry no mark and are judged now
+        late = tup.late
+        if late is None:
+            late = meta is not None and meta["fired"]
+        if late and self.late_policy != "update":
             # drop policy, yet the tuple reached _apply after the fire:
-            # it parked on a state fetch across the window boundary, so
             # its contribution can no longer reach the fired result (and
             # writing would resurrect a purged pane)
             self.late_dropped += 1
@@ -225,7 +234,7 @@ class WindowedStatefulOp(StatefulOp):
             return self.service_time
         acc = self.agg_fn(tup, state)
         emitted = False
-        if meta is not None and meta["fired"]:
+        if late:
             # late-side update: re-emit the refreshed result immediately
             self.late_updates += 1
             payload = self.emit_fn(wk.base, wk.wid,
@@ -266,9 +275,12 @@ class WindowedStatefulOp(StatefulOp):
                 return [Lane(wk, tup.ts, zeros, True, False, tup)]
             # replayed / re-delivered pane access (migration replay is
             # unreachable — fused excludes shards — but recovery
-            # re-delivery lands here): take the fired checks now
-            meta = self.windows[sub].get(wk.wid)
-            if meta is not None and meta["fired"]:
+            # re-delivery lands here): the same lateness rule as _apply
+            late = tup.late
+            if late is None:
+                meta = self.windows[sub].get(wk.wid)
+                late = meta is not None and meta["fired"]
+            if late:
                 if self.late_policy != "update":
                     self.late_dropped += 1
                     self._trace_absorbed(tup.trace)
@@ -369,13 +381,37 @@ class WindowedStatefulOp(StatefulOp):
             elif meta["fired"] and self.allowed_lateness > 0 \
                     and end + self.allowed_lateness < wm:
                 # horizon purge stays one advance behind the fire so FIRE
-                # messages scheduled above are never raced by their purge
+                # messages scheduled above are never raced by their purge.
+                # A pane with tuples parked on its fetch purges once they
+                # have applied (``handle_parked``): they arrived before
+                # this watermark, and when the fetch lands must not
+                # decide whether they count
                 for base in list(meta["keys"]):
-                    self._purge_pane(sub, WindowKey(base, wid))
+                    wk = WindowKey(base, wid)
+                    if wk in self.waiting[sub]:
+                        self._purge_due[sub].add(wk)
+                    else:
+                        self._purge_pane(sub, wk)
         if fire_batch:
-            self.deliver_batch(sub, fire_batch)
+            # the FIREs take the watermark's place in the input queue:
+            # tuples that arrived behind the watermark run after them, so
+            # what a fire counts (and what becomes a late update) does
+            # not depend on how far the operator lagged its input — the
+            # fused and interpreted planes run at different speeds
+            self.queues[sub].extendleft(reversed(fire_batch))
+            self._kick(sub)
+
+    def handle_parked(self, sub: int, tup: Tuple_) -> float:
+        svc = super().handle_parked(sub, tup)
+        wk = tup.key
+        due = self._purge_due[sub]
+        if wk in due and wk not in self.waiting[sub] \
+                and not any(t.key == wk for t in self.ready[sub]):
+            self._purge_pane(sub, wk)       # its last parked tuple applied
+        return svc
 
     def _purge_pane(self, sub: int, wk: WindowKey) -> None:
+        self._purge_due[sub].discard(wk)
         self.caches[sub].drop(wk)
         self.backends[sub].delete(wk)
         self.panes_purged += 1
@@ -471,6 +507,7 @@ class WindowedStatefulOp(StatefulOp):
     def reset_volatile(self) -> None:
         super().reset_volatile()
         self.windows = [dict() for _ in range(self.parallelism)]
+        self._purge_due = [set() for _ in range(self.parallelism)]
 
     # --------------------------------------------------------------- metrics
     def extra_metrics(self) -> Dict[str, Any]:

@@ -428,3 +428,24 @@ def test_fusedplane_flush_and_export_roundtrip():
     assert len(plane) == 0
     plane.import_entries(ents)
     assert plane.lookup("a", 5.0) == 1 and plane.lookup("b", 5.0) == 2
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_fetch_completion_never_rolls_back_a_resident_entry(fused):
+    """A fetch that lands after its key became resident again (served
+    from the write-back memtable while the fetch was in flight) read an
+    OLDER state than the resident one: it renews the entry, it must not
+    overwrite it."""
+    from repro.streaming.engine import _IOReq
+    eng = Engine()
+    kw = dict(fused=count_spec(), fused_batch=8) if fused else {}
+    op = StatefulOp(eng, "agg", 1, lambda t, s: ((s or 0) + 1, []),
+                    LOCAL_NVME, cache_capacity=8 * 64, policy="tac",
+                    mode="async", state_size=64, **kw)
+    eng.add(op)
+    op.backends[0].write("k", 5, 64)          # what the fetch read
+    op.caches[0].insert("k", 6, 1.0, dirty=True, size=64)   # newer
+    op.in_flight[0].add("k")
+    op._io_done(0, _IOReq("prefetch", "k", 4.0), 1e-4)
+    assert op.caches[0].lookup("k", 2.0) == 6
+    assert op.caches[0].flush_dirty()[0].state == 6

@@ -241,3 +241,32 @@ def test_fused_step_composition(kind):
                               kind=kind)
     assert np.asarray(out2.hit).tolist() == [False, False, True, False,
                                              False, False]
+
+
+def test_fused_step_sum_exact_for_fractional_weights():
+    """Sum weights that bf16 cannot hold compose in f32: the in-batch
+    prefix sums equal a sequential f32 fold, which a matmul at the TPU's
+    default precision (f32 operands rounded to bf16) would miss."""
+    from repro.core import tac_jax
+    W, V, B = 8, 3, 16
+    state = tac_jax.init(1, W, 1)
+    pages = jnp.zeros((W + 1, 1, V + 1), jnp.float32)
+    seed = np.asarray([[0.1, 1e3 + 0.3, -2.7]], np.float32)
+    state, pages, _ = tac_jax.fused_admit(
+        state, pages, jnp.zeros(1, jnp.int32), jnp.asarray([5], jnp.int32),
+        jnp.zeros(1, jnp.float32), jnp.asarray(seed), jnp.ones(1, bool),
+        jnp.zeros(1, bool))
+    rng = np.random.RandomState(3)
+    wts = (rng.randn(B, V) * 10 + 1 / 3).astype(np.float32)
+    out = tac_jax.fused_step(state, pages, jnp.full(B, 5, jnp.int32),
+                             jnp.ones(B, jnp.float32), jnp.asarray(wts),
+                             jnp.zeros(B, bool), jnp.ones(B, bool),
+                             kind="sum")
+    acc, ref = seed[0].copy(), []
+    for w in wts:
+        acc = acc + w
+        ref.append(acc.copy())
+    np.testing.assert_allclose(np.asarray(out.new_vals), np.stack(ref),
+                               rtol=1e-6, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out.pages)[0, 0, 1:], ref[-1],
+                               rtol=1e-6, atol=1e-4)

@@ -320,10 +320,9 @@ def test_migration_merges_fired_state_per_key():
     d = win.windows[1][5]
     assert d["keys"] == {0, 1, 4}
     assert d["fired_keys"] == {1}        # moved keys stay fire-eligible
-    batches = []
-    win.deliver_batch = lambda sub, batch: batches.append((sub, batch))
+    win._kick = lambda sub: None         # keep the FIREs queued
     win.on_watermark(1, 6.0)             # dst watermark crosses end(5)=6
-    fired = {t.key.base for _, b in batches for t in b}
+    fired = {t.key.base for t in win.queues[1]}
     assert fired == {0, 4}               # key 1 not refired
     assert d["fired_keys"] == {0, 1, 4}
 
@@ -333,3 +332,69 @@ def test_hash_partition_unwraps_window_keys():
     assert hash_partition(WindowKey(42, 7), 8) == hash_partition(42, 8)
     assert hash_partition(WindowKey(("a", 1), 3), 4) == \
         hash_partition(("a", 1), 4)
+
+
+def test_fire_takes_the_watermark_position_in_the_input():
+    """A pane fires at its watermark's place in the input queue: a tuple
+    already queued BEHIND the watermark is a late update after the fire,
+    so what a fire counts does not depend on how far the operator lags
+    its input (the fused and interpreted planes run at different
+    speeds)."""
+    eng = Engine()
+    win = eng.add(WindowedStatefulOp(
+        eng, "win", 1, WindowAssigner(1.0),
+        agg_fn=lambda tup, acc: (acc or 0) + 1,
+        emit_fn=lambda key, wid, end, acc: ("count", key, wid, acc),
+        backend_model=IN_MEMORY, cache_capacity=1_000_000,
+        allowed_lateness=5.0, late_policy="update", policy="tac",
+        mode="sync", state_size=100))
+    sink = eng.add(_CollectSink(eng, "sink", 1))
+    eng.connect(win, sink, partition=lambda k, n: 0)
+    win.deliver_batch(0, [Tuple_(0.5, 7, None, 100, 0.0), Watermark(1.0),
+                          Tuple_(0.6, 7, None, 100, 0.0)])
+    eng.sim.run_until(1.0)
+    assert [p for _, p in sink.got] == [("count", 7, 0, 1),
+                                         ("count", 7, 0, 2)]
+    assert win.fires == 1 and win.late_updates == 1
+
+
+def _async_count_op(eng, lateness):
+    win = eng.add(WindowedStatefulOp(
+        eng, "win", 1, WindowAssigner(1.0),
+        agg_fn=lambda tup, acc: (acc or 0) + 1,
+        emit_fn=lambda key, wid, end, acc: ("count", key, wid, acc),
+        backend_model=LOCAL_NVME, cache_capacity=1_000_000,
+        allowed_lateness=lateness, late_policy="update", policy="tac",
+        mode="async", state_size=100))
+    sink = eng.add(_CollectSink(eng, "sink", 1))
+    eng.connect(win, sink, partition=lambda k, n: 0)
+    return win, sink
+
+
+def test_tuple_parked_across_the_fire_is_counted_by_it():
+    """Lateness is the tuple's place in the input, fixed at window
+    assignment: an on-time tuple still parked on its pane fetch when the
+    watermark fires the window is counted by the fire (which parks behind
+    it) and emits no late refresh of its own."""
+    eng = Engine()
+    win, sink = _async_count_op(eng, lateness=5.0)
+    win.deliver_batch(0, [Tuple_(0.5, 7, None, 100, 0.0), Watermark(1.0)])
+    eng.sim.run_until(1.0)
+    assert [p for _, p in sink.got] == [("count", 7, 0, 1)]
+    assert win.late_updates == 0 and win.fires == 1
+
+
+def test_horizon_purge_waits_for_tuples_parked_on_the_pane():
+    """A watermark that passes a pane's lateness horizon while an on-time
+    tuple (and the FIRE behind it) is parked on the pane's fetch purges
+    the pane only after both applied — not depending on when the fetch
+    lands."""
+    eng = Engine()
+    win, sink = _async_count_op(eng, lateness=0.5)
+    win.deliver_batch(0, [Tuple_(0.9, 7, None, 100, 0.0), Watermark(1.2),
+                          Watermark(2.0)])
+    eng.sim.run_until(1.0)
+    assert [p for _, p in sink.got] == [("count", 7, 0, 1)]
+    assert win.panes_purged == 1 and WindowKey(7, 0) not in \
+        win.caches[0].entries
+    assert win.late_dropped == 0 and win.fires_lost == 0
