@@ -10,7 +10,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig6,fig7,fig8,fig9,fig10,fig11,"
-                         "tab1,tab2,roofline,claims")
+                         "tab1,tab2,claims")
     ap.add_argument("--out", default="results/bench")
     ap.add_argument("--fail-at", type=float, default=None,
                     help="run the failure/recovery scenario instead of the "
@@ -32,7 +32,7 @@ def main() -> None:
     sys.path.insert(0, root)              # `benchmarks` package itself
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
-    from benchmarks import paper, roofline
+    from benchmarks import paper
     paper.FUSED = args.fused
 
     if args.fail_at is not None:
@@ -92,8 +92,6 @@ def main() -> None:
         paper.tab2(rows, fig6_out)
     if fig6_out and want("claims"):
         paper.validate_claims(rows, fig6_out)
-    if want("roofline"):
-        roofline.roofline_rows(rows)
 
     csv = "\n".join(rows)
     print(csv)

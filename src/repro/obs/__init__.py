@@ -1,7 +1,8 @@
 """Unified observability plane (DESIGN.md §12, §16): metrics registry
 with streaming quantile sketches, per-tuple critical-path tracing,
-prefetch-quality (hint timeliness/accuracy) telemetry, logical-clock
-time series with health detectors, and Perfetto/Chrome-trace export."""
+wall-clock spans of the host's work, prefetch-quality (hint
+timeliness/accuracy) telemetry, logical-clock time series with health
+detectors, and Perfetto/Chrome-trace export."""
 from repro.obs.export import (chrome_trace, read_timeline_jsonl,
                               timeline_jsonl)
 from repro.obs.health import (Alert, Detector, HealthMonitor,
@@ -20,6 +21,7 @@ from repro.obs.registry import (
     QuantileSketch,
     matches_catalog,
 )
+from repro.obs.spans import NULL_SPANS, SpanRecorder
 from repro.obs.timeseries import Interval, Timeline, interval_sketch
 from repro.obs.trace import STAGES, Tracer, TupleTrace, attach
 
@@ -38,8 +40,10 @@ __all__ = [
     "NULL_COUNTER",
     "NULL_GAUGE",
     "NULL_HISTOGRAM",
+    "NULL_SPANS",
     "PrefetchRecorder",
     "QuantileSketch",
+    "SpanRecorder",
     "SpikeDetector",
     "Timeline",
     "chrome_trace",

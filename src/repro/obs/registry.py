@@ -368,6 +368,16 @@ METRIC_CATALOG: Dict[str, str] = {
     "engine.<op>.fused.device_conflicts":
         "device misses adjudicated while the plane was FULL (admission "
         "must evict — the streaming analogue of serving probe conflicts)",
+    "engine.<op>.fused.calls.<program>":
+        "device calls by program: fused_step|fused_admit|gather_rows|"
+        "drop_slots",
+    "engine.<op>.fused.victim_reads":
+        "dirty victims read back from the device pool (one gather each)",
+    # wall-clock host spans (repro.obs.spans; on after enable_spans):
+    # <owner> is an operator, channel, engine, or fused (plane phases)
+    "engine.span.<owner>.<name>.count": "spans closed",
+    "engine.span.<owner>.<name>.self_s":
+        "wall time inside the span less nested spans (s)",
     # TAC eviction-reason breakdown, split by admission path
     "engine.<op>.evict.<reason>.<adm>":
         "evictions by reason (capacity|deadline|stale) and admission "

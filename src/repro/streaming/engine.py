@@ -22,8 +22,9 @@ from repro.core.policies import ClockCache, LRUCache
 from repro.core.prefetch import (LookaheadCandidate, PrefetchingController,
                                  PrefetchingManager)
 from repro.core.tac import TimestampAwareCache
-from repro.obs import (HealthMonitor, MetricsRegistry, PrefetchRecorder,
-                       QuantileSketch, Timeline, Tracer)
+from repro.obs import (NULL_SPANS, HealthMonitor, MetricsRegistry,
+                       PrefetchRecorder, QuantileSketch, SpanRecorder,
+                       Timeline, Tracer)
 from repro.runtime.compression import hint_batch_nbytes
 from repro.streaming.backend import BackendModel, StateBackend
 from repro.streaming.fused import FusedPlane, FusedSpec, Lane
@@ -51,10 +52,13 @@ FUSED_LANE = 0.3e-6               # per-lane share of a fused batch: the
 
 
 class Sim:
-    def __init__(self):
+    def __init__(self, spans: Optional[SpanRecorder] = None):
         self.t = 0.0
         self._heap: List = []
         self._seq = itertools.count()
+        # wall-clock spans (DESIGN.md §12): one per dispatched callback
+        # while the engine's recorder is on
+        self.spans = spans if spans is not None else NULL_SPANS
 
     def at(self, t: float, fn: Callable, *args) -> None:
         heapq.heappush(self._heap, (t, next(self._seq), fn, args))
@@ -63,10 +67,13 @@ class Sim:
         self.at(self.t + delay, fn, *args)
 
     def run_until(self, t_end: float) -> None:
-        while self._heap and self._heap[0][0] <= t_end:
-            t, _, fn, args = heapq.heappop(self._heap)
-            self.t = t
-            fn(*args)
+        if self.spans.enabled:
+            self.spans.dispatch(self, t_end)
+        else:
+            while self._heap and self._heap[0][0] <= t_end:
+                t, _, fn, args = heapq.heappop(self._heap)
+                self.t = t
+                fn(*args)
         self.t = max(self.t, t_end)
 
     def purge(self, pred: Callable[[Tuple], bool]) -> int:
@@ -778,6 +785,8 @@ class StatefulOp(Operator):
                 raise ValueError("fused mode requires policy='tac'")
         self.fused_spec = fused
         self.fused_batch = int(fused_batch)
+        self._span_drain = f"stream.{name}.drain"
+        self._span_adjudicate = f"stream.{name}.adjudicate"
         self.shards = shards
         self.shard_pending: Dict[int, List[Any]] = {}
         self.apply_fn = apply_fn           # (tup, state) -> (state', outputs)
@@ -858,7 +867,8 @@ class StatefulOp(Operator):
                               entry_size=self.state_size,
                               spec=self.fused_spec,
                               deadline_aware=self.deadline_aware,
-                              batch=self.fused_batch)
+                              batch=self.fused_batch,
+                              spans=self.engine.spans)
         if self.policy == "tac":
             # deadline_aware: window panes carry far-future fire
             # deadlines, where plain min-ts eviction would remove the
@@ -1315,6 +1325,9 @@ class StatefulOp(Operator):
         width, at the first non-data message, or at a fire/update
         conflict (the conflicting tuple waits for the next batch, which
         preserves sequential per-key semantics)."""
+        spans = self.engine.spans
+        if spans.enabled:
+            spans.enter(self._span_drain)
         q = self.queues[sub]
         B = self.fused_batch
         lanes: List[Lane] = []
@@ -1343,6 +1356,8 @@ class StatefulOp(Operator):
         # in-order chunks of one drain preserve per-key sequencing
         for i in range(0, len(lanes), B):
             svc += self._fused_step(sub, lanes[i:i + B])
+        if spans.enabled:
+            spans.exit()
         return svc
 
     def _fused_step(self, sub: int, lanes: List[Lane]) -> float:
@@ -1357,6 +1372,9 @@ class StatefulOp(Operator):
         spec = self.fused_spec
         n = len(lanes)
         res = plane.batch_step(lanes)
+        spans = self.engine.spans
+        if spans.enabled:
+            spans.enter(self._span_adjudicate)
         svc = FUSED_LAUNCH + FUSED_LANE * n
         if self.mode == "prefetch":
             mgr.prefetch_hits += int(res.hit.sum())
@@ -1458,6 +1476,8 @@ class StatefulOp(Operator):
                                  front=True)
             svc += IO_ISSUE * (1.0 + len(self.in_flight[sub]) / 32.0)
         self._io_kick(sub)          # opportunistic write-back, per batch
+        if spans.enabled:
+            spans.exit()
         return svc
 
     def periodic_evaluate(self) -> None:
@@ -1598,7 +1618,10 @@ class Engine:
     """
 
     def __init__(self, marker_interval: float = 0.100):
-        self.sim = Sim()
+        # wall-clock spans of the host's work (DESIGN.md §12): off
+        # unless enable_spans; shared with the Sim and every FusedPlane
+        self.spans = SpanRecorder()
+        self.sim = Sim(self.spans)
         self.controller = PrefetchingController(marker_interval)
         self.operators: Dict[str, Operator] = {}
         self._candidate_ops: Dict[str, List[str]] = {}
@@ -1748,6 +1771,15 @@ class Engine:
         sink.  Off by default — the disabled cost is one flag check per
         source tuple."""
         self.tracer.enable(sample_every)
+
+    def enable_spans(self) -> None:
+        """Turn on the wall-clock span recorder (DESIGN.md §12): a span
+        per dispatched callback, per fused drain and adjudication, and
+        per fused-plane phase, each a profiler TraceAnnotation and a
+        per-name count and self time in ``self.spans``.  Off by default
+        — the disabled cost is one flag check per ``run_until`` and per
+        fused-plane site."""
+        self.spans.enable()
 
     def enable_export(self, path: str, interval: float = 1.0) -> None:
         """Append a registry snapshot line to ``path`` every ``interval``
@@ -1973,6 +2005,9 @@ class Engine:
                         "device_misses": sum(c.device_misses for c in fp),
                         "device_conflicts": sum(c.device_conflicts
                                                 for c in fp),
+                        "calls": {prog: sum(c.calls[prog] for c in fp)
+                                  for prog in FusedPlane.PROGRAMS},
+                        "victim_reads": sum(c.victim_reads for c in fp),
                     }
                 if op.shards is not None:
                     # per-shard routed-plane counters (DESIGN.md §9), not
@@ -2008,6 +2043,11 @@ class Engine:
         if self.tracer.active:
             # sampled critical-path breakdown (DESIGN.md §12)
             out["trace"] = self.tracer.summary()
+        if self.spans.enabled:
+            # host wall time by span, self time (DESIGN.md §12)
+            spans = self.spans.snapshot()["spans"]
+            out["spans"] = {n: {"count": c, "self_s": ns / 1e9}
+                            for n, (c, ns) in sorted(spans.items())}
         if self.timeline is not None:
             # temporal-plane rollup (DESIGN.md §16)
             out["timeline"] = self.timeline.block()
@@ -2094,8 +2134,18 @@ class Engine:
                     sum(c.device_misses for c in fp))
                 r.counter(f"{pre}.fused.device_conflicts").set(
                     sum(c.device_conflicts for c in fp))
+                for prog in FusedPlane.PROGRAMS:
+                    r.counter(f"{pre}.fused.calls.{prog}").set(
+                        sum(c.calls[prog] for c in fp))
+                r.counter(f"{pre}.fused.victim_reads").set(
+                    sum(c.victim_reads for c in fp))
             if op.shards is not None:
                 op.shards.registry_sync(r, pre, op.shard_pending)
+        if self.spans.enabled:
+            for n, (c, ns) in self.spans.snapshot()["spans"].items():
+                pre = "engine.span." + n.split(".", 1)[1]
+                r.counter(f"{pre}.count").set(c)
+                r.gauge(f"{pre}.self_s").set(ns / 1e9)
         r.counter("engine.net.data_bytes").set(int(data_bytes))
         r.counter("engine.net.hint_bytes").set(int(hint_bytes))
         r.gauge("engine.cpu.util").set(

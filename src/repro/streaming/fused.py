@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from repro.core.tac import Entry
+from repro.obs.spans import NULL_SPANS, SpanRecorder
 
 # jax/device imports are deferred so stdlib-only tooling can import the
 # module namespace; the plane itself requires the device stack.
@@ -141,11 +142,17 @@ class FusedPlane:
     PAD_KEY = -2              # never matches empty (-1) or interned (>=0)
     DROP_W = 32               # fixed width of the batched directory clear
 
+    PROGRAMS = ("fused_step", "fused_admit", "gather_rows", "drop_slots")
+
     def __init__(self, capacity: int, entry_size: int, spec: FusedSpec,
-                 deadline_aware: bool = False, batch: int = 64):
+                 deadline_aware: bool = False, batch: int = 64,
+                 spans: Optional[SpanRecorder] = None):
         import jax.numpy as jnp
         from repro.core import tac_jax
         self._tj = tac_jax
+        # wall-clock spans of the host side (DESIGN.md §12): the owning
+        # engine's recorder, or the shared disabled one
+        self.spans = spans if spans is not None else NULL_SPANS
         self._jnp = jnp
         self.spec = spec
         self.batch = int(batch)
@@ -213,6 +220,9 @@ class FusedPlane:
         self.device_hits = 0
         self.device_misses = 0
         self.device_conflicts = 0
+        # one per device call, by program; dirty victims read back
+        self.calls: Dict[str, int] = dict.fromkeys(self.PROGRAMS, 0)
+        self.victim_reads = 0
 
     # ------------------------------------------------------------ internals
     def _intern(self, key) -> int:
@@ -231,6 +241,9 @@ class FusedPlane:
             return
         drops = self._pending_drops
         self._pending_drops = []
+        spans = self.spans
+        if spans.enabled:
+            spans.enter("stream.fused.drop")
         for i in range(0, len(drops), self.DROP_W):
             chunk = drops[i:i + self.DROP_W]
             slots = np.zeros(self.DROP_W, np.int32)
@@ -239,7 +252,10 @@ class FusedPlane:
             valid[:len(chunk)] = True
             # np arrays go straight into the jitted call: jit's argument
             # path converts in ~us, an explicit device put costs ~100x
+            self.calls["drop_slots"] += 1
             self.tac = self._tj.drop_slots(self.tac, slots, valid)
+        if spans.enabled:
+            spans.exit()
 
     def _flush_admits(self) -> None:
         """Land the queued admissions.  Chunks pad to a few fixed widths
@@ -249,6 +265,9 @@ class FusedPlane:
         queued admit can target the same slot, and the admit wins."""
         if not self._pending_admits:
             return
+        spans = self.spans
+        if spans.enabled:
+            spans.enter("stream.fused.admit")
         recs = list(self._pending_admits.items())
         self._pending_admits.clear()
         self._pending_state.clear()
@@ -267,9 +286,12 @@ class FusedPlane:
             rows = np.asarray([r[2] for r in rs], np.float32)
             pres = np.asarray([r[3] for r in rs], bool)
             dirty = np.asarray([r[4] for r in rs], bool)
+            self.calls["fused_admit"] += 1
             self.tac, self.pages, _ = self._tj.fused_admit(
                 self.tac, self.pages, slots, kids, ts, rows, pres,
                 dirty)
+        if spans.enabled:
+            spans.exit()
 
     def _sync(self) -> None:
         self._flush_drops()
@@ -343,9 +365,8 @@ class FusedPlane:
             if pend is not None:
                 state = self.spec.dec(pend[0], pend[1])
             else:
-                row = np.asarray(self._tj.gather_rows(
-                    self.pages, np.array([slot], np.int32)))[0, 0]
-                state = self.spec.dec(row[1:], row[0] > 0.5)
+                self.victim_reads += 1
+                state = self._gather_one(slot, "stream.fused.victim_read")
             e = Entry(key, state, float(self._sts[slot]), True,
                       self.entry_size)
             e.prefetched = bool(self._spf[slot])
@@ -397,8 +418,18 @@ class FusedPlane:
         pend = self._pending_state.get(slot)
         if pend is not None:
             return self.spec.dec(pend[0], pend[1])
+        return self._gather_one(slot, "stream.fused.slot_read")
+
+    def _gather_one(self, slot: int, span: str):
+        """One pool row read back to the host, decoded."""
+        spans = self.spans
+        if spans.enabled:
+            spans.enter(span)
+        self.calls["gather_rows"] += 1
         row = np.asarray(self._tj.gather_rows(
             self.pages, np.array([slot], np.int32)))[0, 0]
+        if spans.enabled:
+            spans.exit()
         return self.spec.dec(row[1:], row[0] > 0.5)
 
     def _restore(self, staged: Entry, ts: float) -> None:
@@ -499,7 +530,13 @@ class FusedPlane:
     # ------------------------------------------------------- bulk/cold ops
     def _pool_host(self) -> np.ndarray:
         self._flush_admits()
-        return np.asarray(self.pages)
+        spans = self.spans
+        if spans.enabled:
+            spans.enter("stream.fused.pool_read")
+        pool = np.asarray(self.pages)
+        if spans.enabled:
+            spans.exit()
+        return pool
 
     def _entry_at(self, slot: int, pool: np.ndarray) -> Entry:
         row = pool[slot, 0]
@@ -583,12 +620,20 @@ class FusedPlane:
         the same drain, true misses to park), which keeps the §12
         hit/miss counters exactly sequential-equivalent.  Device tallies
         fold into ``device_hits``/``device_misses``.
+
+        With spans on, the call splits into ``stream.fused.stage``
+        (flushes and staging), ``dispatch`` (the jitted call),
+        ``readback`` (the host blocked on the outputs) and ``shadow``.
         """
-        self._sync()
         n = len(lanes)
         B = self.batch
         if n > B:
             raise ValueError(f"batch of {n} lanes exceeds width {B}")
+        spans = self.spans
+        on = spans.enabled
+        if on:
+            spans.enter("stream.fused.stage")
+        self._sync()
         V = self.spec.width
         # bulk staging: one fromiter/asarray per field beats per-lane
         # numpy scalar writes by ~50x at B=64
@@ -605,15 +650,22 @@ class FusedPlane:
         fire[:n] = np.fromiter((ln.fire for ln in lanes), bool, n)
         valid = np.zeros(B, bool)
         valid[:n] = True
+        if on:
+            spans.switch("stream.fused.dispatch")
+        self.calls["fused_step"] += 1
         out = self._tj.fused_step(self.tac, self.pages, keys, ts32,
                                   weights, fire, valid,
                                   kind=self.spec.kind)
         self.tac, self.pages = out.state, out.pages
+        if on:
+            spans.switch("stream.fused.readback")
         hit = np.asarray(out.hit)[:n]
         slots = np.asarray(out.slots)[:n]
         new_vals = np.asarray(out.new_vals)[:n]
         present = np.asarray(out.present)[:n]
         tallies = np.asarray(out.tallies)
+        if on:
+            spans.switch("stream.fused.shadow")
         self.batches += 1
         self.lanes += n
         misses = int(tallies[1])
@@ -650,6 +702,8 @@ class FusedPlane:
                 for s in np.unique(first):
                     self.recorder.on_used(float(self._sstage_t[s]))
             self._spf_unused[hs] = False
+        if on:
+            spans.exit()
         return BatchResult(hit, present, new_vals, fire[:n])
 
     def decode_lane(self, res: BatchResult, i: int):

@@ -96,6 +96,10 @@ def test_catalog_template_matching():
     assert matches_catalog("engine.join.evict.capacity.prefetched")
     assert matches_catalog("engine.stateful.shard.7.hints_routed")
     assert matches_catalog("trace.stage.park_wait")
+    assert matches_catalog("engine.stateful.fused.calls.gather_rows")
+    assert matches_catalog("engine.stateful.fused.victim_reads")
+    assert matches_catalog("engine.span.fused.readback.self_s")
+    assert matches_catalog("engine.span.source.tick.count")
     assert not matches_catalog("engine.nope")
     assert not matches_catalog("engine.stateful.evict.capacity")  # arity
     assert not matches_catalog("made.up.metric")

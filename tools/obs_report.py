@@ -4,11 +4,13 @@
 Three modes:
 
   * default — build the q5 smoke pipeline (same config as the windowing
-    benchmark's smoke tier), run it with per-tuple tracing enabled, and
-    print the critical-path latency breakdown: a per-stage table (count,
-    mean, p50, p99, total, share) with the DOMINANT stage flagged, the
-    hint-quality block (staged/used/wasted/late, precision, recall,
-    signed lead-time percentiles), and the eviction-reason split;
+    benchmark's smoke tier), run it with per-tuple tracing and the
+    wall-clock span recorder enabled, and print the critical-path
+    latency breakdown: a per-stage table (count, mean, p50, p99, total,
+    share) with the DOMINANT stage flagged, the hint-quality block
+    (staged/used/wasted/late, precision, recall, signed lead-time
+    percentiles), the eviction-reason split, and the host's wall time
+    by span (self time, largest first);
   * ``--timeline`` — run the same pipeline with the temporal plane
     enabled (DESIGN.md §16) and print the per-interval view: precision,
     recall, watermark lag, and hit-rate series on the logical clock with
@@ -99,6 +101,23 @@ def print_fused(fb: dict) -> None:
     print(f"  {'device misses':<16s} {fb.get('device_misses', 0):>8d}")
     print(f"  {'conflicts':<16s} {fb.get('device_conflicts', 0):>8d}   "
           f"(misses beyond free device slots at adjudication)")
+    for prog, n in fb.get("calls", {}).items():
+        print(f"  {'calls ' + prog:<16s} {n:>8d}")
+    print(f"  {'victim reads':<16s} {fb.get('victim_reads', 0):>8d}   "
+          f"(dirty victims read back from the device)")
+
+
+def print_spans(spans: dict) -> None:
+    """Host wall time by span (DESIGN.md §12), self time, largest first:
+    which callback, drain, adjudication or device wait the host spends
+    its time in."""
+    if not spans:
+        return
+    total = sum(s["self_s"] for s in spans.values()) or 1.0
+    print("\nhost wall time by span (self time):")
+    for name, s in sorted(spans.items(), key=lambda kv: -kv[1]["self_s"]):
+        print(f"  {name:<36s} {s['count']:>8d} "
+              f"{s['self_s'] * 1e3:>10.2f}ms {s['self_s'] / total:>6.1%}")
 
 
 SPARK = "▁▂▃▄▅▆▇█"
@@ -136,6 +155,7 @@ def _build_smoke(args):
 def run_report(args) -> int:
     eng = _build_smoke(args)
     eng.enable_tracing(sample_every=args.sample_every)
+    eng.enable_spans()
     if args.export:
         eng.enable_export(args.export, interval=0.5)
     m = eng.run(duration=args.duration, warmup=args.warmup)
@@ -149,6 +169,7 @@ def run_report(args) -> int:
     print_quality(m.get("stateful_hint_quality", {}),
                   m.get("stateful_evictions", {}))
     print_fused(m.get("stateful_fused", {}))
+    print_spans(m.get("spans", {}))
     if args.export:
         print(f"\nregistry snapshots appended to {args.export}")
     return 0
