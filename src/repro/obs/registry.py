@@ -372,7 +372,10 @@ METRIC_CATALOG: Dict[str, str] = {
         "device calls by program: fused_step|fused_admit|gather_rows|"
         "drop_slots",
     "engine.<op>.fused.victim_reads":
-        "dirty victims read back from the device pool (one gather each)",
+        "dirty victims with no queued row, read from the value shadow",
+    "engine.<op>.fused.shadow_reads":
+        "rows served from the host value shadow (victims and slot "
+        "reads), each in place of a device gather",
     # wall-clock host spans (repro.obs.spans; on after enable_spans):
     # <owner> is an operator, channel, engine, or fused (plane phases)
     "engine.span.<owner>.<name>.count": "spans closed",

@@ -2008,6 +2008,7 @@ class Engine:
                         "calls": {prog: sum(c.calls[prog] for c in fp)
                                   for prog in FusedPlane.PROGRAMS},
                         "victim_reads": sum(c.victim_reads for c in fp),
+                        "shadow_reads": sum(c.shadow_reads for c in fp),
                     }
                 if op.shards is not None:
                     # per-shard routed-plane counters (DESIGN.md §9), not
@@ -2139,6 +2140,8 @@ class Engine:
                         sum(c.calls[prog] for c in fp))
                 r.counter(f"{pre}.fused.victim_reads").set(
                     sum(c.victim_reads for c in fp))
+                r.counter(f"{pre}.fused.shadow_reads").set(
+                    sum(c.shadow_reads for c in fp))
             if op.shards is not None:
                 op.shards.registry_sync(r, pre, op.shard_pending)
         if self.spans.enabled:

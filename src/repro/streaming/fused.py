@@ -21,7 +21,10 @@ Two data structures cooperate:
     insertion-generation tie-break replicating the reference heap) and
     slot assignment; the device owns membership and payloads.  Both
     change only through the entry points below, so they agree by
-    construction.
+    construction.  A value shadow (``_sval``/``_spres``) keeps a copy
+    of every occupied slot's pool row, written where the device's two
+    writers write (``_place`` for ``fused_admit``, ``batch_step`` for
+    ``fused_step``), so single-row reads never cross the bus.
 
 ``FusedPlane`` implements the full ``TimestampAwareCache`` interface
 (lookup/insert/write/renew/drop/pop_writeback/flush_dirty/export/import/
@@ -134,9 +137,10 @@ class FusedPlane:
     """Device-resident keyed-state plane with TAC-compatible semantics.
 
     Capacity is counted in the same size units as ``TimestampAwareCache``
-    (``capacity // entry_size`` uniform slots).  Single-key operations
-    (the engine's cold paths) each cost one small device call; the hot
-    path is ``batch_step``.
+    (``capacity // entry_size`` uniform slots).  Single-key reads (the
+    engine's cold paths) are served from the host value shadow and
+    single-key writes queue for the next admission flush; the hot path
+    is ``batch_step``.
     """
 
     PAD_KEY = -2              # never matches empty (-1) or interned (>=0)
@@ -173,6 +177,10 @@ class FusedPlane:
         self._spf_unused = np.zeros(W, bool)        # staged, never read
         self._sstage_t = np.zeros(W, np.float64)
         self._sorigin: List[str] = [""] * W
+        # host value shadow (§14): the row the pool holds, or will hold
+        # once the queued admissions land, at every occupied slot
+        self._sval = np.zeros((W, V), np.float32)
+        self._spres = np.zeros(W, bool)
         self._key_by_slot: List[Any] = [None] * W
         self._slot_by_key: Dict[Any, int] = {}
         self._free: List[int] = list(range(W - 1, -1, -1))
@@ -220,9 +228,11 @@ class FusedPlane:
         self.device_hits = 0
         self.device_misses = 0
         self.device_conflicts = 0
-        # one per device call, by program; dirty victims read back
+        # one per device call, by program; dirty victims read back; rows
+        # served from the value shadow (victims and slot reads)
         self.calls: Dict[str, int] = dict.fromkeys(self.PROGRAMS, 0)
         self.victim_reads = 0
+        self.shadow_reads = 0
 
     # ------------------------------------------------------------ internals
     def _intern(self, key) -> int:
@@ -346,8 +356,9 @@ class FusedPlane:
     def _account_eviction(self, slot: int, reason: str) -> None:
         """Runs BEFORE the new occupant is queued at ``slot``.  A dirty
         victim's value comes from its own queued admission if it never
-        reached the device, else from a single-row pool gather — clean
-        victims (the common prefetch-churn case) touch nothing."""
+        reached the device, else from the value shadow — no device call
+        either way; clean victims (the common prefetch-churn case) touch
+        nothing."""
         key = self._key_by_slot[slot]
         self.evictions += 1
         adm = "prefetched" if self._spf[slot] else "demand"
@@ -366,7 +377,7 @@ class FusedPlane:
                 state = self.spec.dec(pend[0], pend[1])
             else:
                 self.victim_reads += 1
-                state = self._gather_one(slot, "stream.fused.victim_read")
+                state = self._shadow_read(slot, "stream.fused.victim_read")
             e = Entry(key, state, float(self._sts[slot]), True,
                       self.entry_size)
             e.prefetched = bool(self._spf[slot])
@@ -401,6 +412,8 @@ class FusedPlane:
         self._pending_admits[slot] = [self._intern(key), float(ts), vec,
                                       present, dirty]
         self._pending_state[slot] = (vec, present)
+        self._sval[slot] = vec
+        self._spres[slot] = present
         self._sid[slot] = self._ids[key]
         self._sts[slot] = ts
         self._sgen[slot] = self._next_gen()
@@ -418,19 +431,20 @@ class FusedPlane:
         pend = self._pending_state.get(slot)
         if pend is not None:
             return self.spec.dec(pend[0], pend[1])
-        return self._gather_one(slot, "stream.fused.slot_read")
+        return self._shadow_read(slot, "stream.fused.slot_read")
 
-    def _gather_one(self, slot: int, span: str):
-        """One pool row read back to the host, decoded."""
+    def _shadow_read(self, slot: int, span: str):
+        """One slot's row from the value shadow, decoded: what the pool
+        holds there, with no device call."""
         spans = self.spans
         if spans.enabled:
             spans.enter(span)
-        self.calls["gather_rows"] += 1
-        row = np.asarray(self._tj.gather_rows(
-            self.pages, np.array([slot], np.int32)))[0, 0]
+        self.shadow_reads += 1
+        state = self.spec.dec(self._sval[slot].copy(),
+                              bool(self._spres[slot]))
         if spans.enabled:
             spans.exit()
-        return self.spec.dec(row[1:], row[0] > 0.5)
+        return state
 
     def _restore(self, staged: Entry, ts: float) -> None:
         """Eviction-buffer restore (the paper's staged-entry move-back):
@@ -696,6 +710,15 @@ class FusedPlane:
             if self.spec.kind != "read":
                 upd = hit & ~fire[:n]
                 self._sdirty[slots[upd]] = True
+                # value shadow: the row the device scattered, i.e. each
+                # updated slot's LAST update lane (last-write-wins),
+                # chosen explicitly rather than by numpy's order for
+                # repeated fancy-index assignment
+                ui = np.flatnonzero(upd)[::-1]
+                us, first = np.unique(slots[ui], return_index=True)
+                last = ui[first]
+                self._sval[us] = new_vals[last]
+                self._spres[us] = present[last]
             # first read of staged entries: signed lead time (§12)
             first = hs[self._spf_unused[hs]]
             if len(first) and self.recorder is not None:

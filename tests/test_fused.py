@@ -67,6 +67,21 @@ def _final_state(op, state_size):
     return dict(op.backends[0].data)
 
 
+def assert_shadow_is_pool(plane):
+    """The host value shadow holds, bit for bit, the pool row of every
+    occupied slot once the queued admissions have landed."""
+    import numpy as np
+    plane._sync()
+    pool = np.asarray(plane.pages)
+    occ = np.asarray(sorted(plane._slot_by_key.values()), np.int64)
+    if not len(occ):
+        return
+    assert np.array_equal(plane._sval[occ].view(np.uint32),
+                          pool[occ, 0, 1:].view(np.uint32))
+    assert np.array_equal(plane._spres[occ], pool[occ, 0, 0] > 0.5)
+    assert np.isin(pool[occ, 0, 0], (0.0, 1.0)).all()
+
+
 # ------------------------------------------------------------ base operator
 def run_base(keys, fused, cache_entries=8, batch=8):
     """Count-per-key through a bare StatefulOp under the quiesced
@@ -89,7 +104,11 @@ def run_base(keys, fused, cache_entries=8, batch=8):
                              for j in range(i, min(i + 6, len(keys)))])
         t += 0.05
         eng.sim.run_until(t)
+        if fused:
+            assert_shadow_is_pool(op.caches[0])
     eng.sim.run_until(t + 1.0)
+    if fused:
+        assert_shadow_is_pool(op.caches[0])
     return _final_state(op, 64), _counters(op)
 
 
@@ -172,8 +191,12 @@ def run_windowed(keys_ts, fused, lateness, late_policy, size=10.0,
         op.deliver_batch(0, [Watermark(hi - wm_lag)])
         t += 0.05
         eng.sim.run_until(t)
+        if fused:
+            assert_shadow_is_pool(op.caches[0])
     op.deliver_batch(0, [Watermark(hi + 1000.0)])
     eng.sim.run_until(t + 2.0)
+    if fused:
+        assert_shadow_is_pool(op.caches[0])
     for lanes in batches:
         fires = {ln.key for ln in lanes if ln.fire}
         upds = {ln.key for ln in lanes if not ln.fire}
@@ -415,6 +438,86 @@ def test_fusedplane_batch_step_composes_duplicates():
         [Lane("nope", 4.0, spec.weight(None), False, False, None)])
     assert not miss.hit.any() and plane.device_misses == 1
     assert isinstance(res.new_vals, np.ndarray)
+
+
+def test_value_shadow_takes_the_last_update_lane_to_the_victim():
+    """Two update lanes of one key in one batch, then that key's
+    eviction: the victim written back carries the LAST lane's value
+    (the device scatter's last-write-wins), as the interpreted TAC's
+    lane-by-lane loop does, and no row is read from the device."""
+    from repro.core.tac import TimestampAwareCache
+    spec = count_spec()
+    plane = FusedPlane(2 * 8, 8, spec, batch=8)
+    ref = TimestampAwareCache(2)
+    for c in (plane, ref):
+        c.insert("k", 10, 1.0, dirty=False)
+        c.insert("j", 1, 1.5, dirty=False)
+    lanes = [Lane("k", 2.0, (2.0,), False, False, None),
+             Lane("k", 2.5, (3.0,), False, False, None)]
+    res = plane.batch_step(lanes)
+    assert res.hit.all()
+    for ln in lanes:
+        ref.write("k", ref.lookup("k", ln.ts) + int(ln.weight[0]), ln.ts)
+    assert_shadow_is_pool(plane)
+    for c in (plane, ref):
+        c.renew("j", 3.0)
+        c.insert("m", 0, 4.0, dirty=False)        # evicts "k", dirty
+    assert plane.evict_buffer["k"].state == ref.evict_buffer["k"].state \
+        == 15
+    assert plane.victim_reads == plane.shadow_reads == 1
+    assert plane.calls["gather_rows"] == 0
+    assert_shadow_is_pool(plane)
+
+
+def _query_engine(query, seed):
+    if query == "ysb":
+        from repro.streaming.ysb import YSBConfig, build_ysb
+        cfg = YSBConfig(rate=2_000.0, n_ads=5_000, seed=seed)
+        return build_ysb("tac", "prefetch", cfg, fused=True, fused_batch=64,
+                         cache_entries=256, parallelism=1,
+                         source_parallelism=1)
+    from repro.streaming.nexmark import NexmarkConfig, build_query
+    cfg = NexmarkConfig(rate=2_000.0, active_window=60.0, oo_bound=0.3,
+                        seed=seed)
+    return build_query(query, "tac", "prefetch", cfg, fused=True,
+                       fused_batch=64, cache_entries=256, parallelism=1,
+                       source_parallelism=1)
+
+
+@pytest.mark.parametrize("query,kind", [("q5", "sum"), ("q7", "max"),
+                                        ("ysb", "read")])
+def test_value_shadow_is_the_pool_in_query_runs(query, kind):
+    """Small fused runs of windowed counts with evictions (q5), a
+    windowed max (q7) and the read-only join (YSB): before and after
+    every device batch, and after the drain, the value shadow equals
+    the pool at every occupied slot."""
+    from repro.streaming.engine import SourceOp
+    eng = _query_engine(query, 11)
+    op = eng.operators["stateful"]
+    plane = op.caches[0]
+    assert isinstance(plane, FusedPlane) and plane.spec.kind == kind
+    step, checked = plane.batch_step, [0]
+
+    def checked_step(lanes):
+        assert_shadow_is_pool(plane)
+        res = step(lanes)
+        assert_shadow_is_pool(plane)
+        checked[0] += 1
+        return res
+    plane.batch_step = checked_step
+    eng.run(duration=2.0)
+    for src in eng.operators.values():
+        if isinstance(src, SourceOp):
+            src.stopped = True
+    eng.sim.run_until(20.0)
+    assert_shadow_is_pool(plane)
+    assert checked[0] > 10
+    assert plane.calls["gather_rows"] == 0
+    if query == "q5":                 # dirty panes evicted and read back
+        assert plane.victim_reads > 0
+    if query == "ysb":
+        assert plane.victim_reads == 0
+    assert plane.shadow_reads >= plane.victim_reads
 
 
 def test_fusedplane_flush_and_export_roundtrip():

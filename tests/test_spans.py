@@ -319,6 +319,27 @@ def test_victim_reads_are_the_dirty_evictions_read_back(query):
 
 
 @pytest.mark.parametrize("query", ["q5", "ysb"])
+def test_rows_are_read_from_the_shadow(query):
+    """Dirty victims and slot reads come from the host value shadow:
+    no row crosses the bus, and every victim read is a shadow read."""
+    off, on = _runs(query)
+    for run in (off, on):
+        assert sum(p.calls["gather_rows"] for p in run["planes"]) == 0
+        assert run["outside"]["gather_rows"] == 0
+    victims = sum(p.victim_reads for p in on["planes"])
+    shadow = sum(p.shadow_reads for p in on["planes"])
+    assert shadow >= victims
+    assert on["metrics"]["stateful_fused"]["shadow_reads"] == shadow
+    if query == "q5":
+        assert victims > 0
+    else:                             # the join's state is read-only
+        assert victims == 0
+    snap = on["eng"].registry.snapshot()
+    name = "engine.stateful.fused.shadow_reads"
+    assert snap[name] == shadow and matches_catalog(name)
+
+
+@pytest.mark.parametrize("query", ["q5", "ysb"])
 def test_self_times_and_unspanned_time_add_up_to_the_wall(query):
     _, on = _runs(query)
     s0, s1 = on["s0"], on["s1"]

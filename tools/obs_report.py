@@ -104,7 +104,9 @@ def print_fused(fb: dict) -> None:
     for prog, n in fb.get("calls", {}).items():
         print(f"  {'calls ' + prog:<16s} {n:>8d}")
     print(f"  {'victim reads':<16s} {fb.get('victim_reads', 0):>8d}   "
-          f"(dirty victims read back from the device)")
+          f"(dirty victims with no queued row)")
+    print(f"  {'shadow reads':<16s} {fb.get('shadow_reads', 0):>8d}   "
+          f"(rows served from the host value shadow)")
 
 
 def print_spans(spans: dict) -> None:
