@@ -15,7 +15,6 @@ serving arena's write-back path needs them).
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, NamedTuple, Tuple
 
 import jax
@@ -464,28 +463,7 @@ class FusedStep(NamedTuple):
     tallies: jax.Array    # [2] int32  (hits, misses) over valid lanes
 
 
-@partial(jax.jit, static_argnames=("kind",))
-def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
-               ts: jax.Array, weights: jax.Array, fire: jax.Array,
-               valid: jax.Array, *, kind: str = "sum") -> FusedStep:
-    """One fused batch over the resident working set.
-
-    ``kind`` picks the operator compute (static — one compiled program
-    per operator config): ``sum`` (count is sum of ones), ``max``, or
-    ``read`` (no state update, read-only enrichment).  ``weights`` is
-    ``[B, V]``; ``fire`` lanes read the pane without updating it.
-
-    Duplicate keys in one batch compose EXACTLY as the interpreted
-    sequential loop: lane i's ``new_vals`` folds in every earlier
-    same-key update lane (lower-triangular mask), and the scatter's
-    last-write-wins grid order leaves the final composed value in the
-    pool.  The batching contract (streaming/fused.py) never mixes a fire
-    lane and an update lane of the same key in one batch.
-
-    Miss lanes are NOT admitted here — the host parks their tuples and
-    admissions arrive later through ``fused_admit`` (the asynchronous
-    fetch path, DESIGN.md §2) — so a miss lane's only trace is its tally.
-    """
+def _fused_step(state, pages, keys, ts, weights, fire, valid, kind):
     B = keys.shape[0]
     n_buckets, ways = state.keys.shape
     trash = pages.shape[0] - 1
@@ -535,6 +513,51 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
                         ).astype(jnp.int32)
     return FusedStep(state._replace(ts=new_ts, dirty=dirty), pages,
                      hit, slots, new_v, present, tallies)
+
+
+def _step_program(kind: str):
+    def step(state, pages, keys, ts, weights, fire, valid):
+        return _fused_step(state, pages, keys, ts, weights, fire, valid,
+                           kind)
+    step.__name__ = step.__qualname__ = f"fused_step_{kind}"
+    return jax.jit(step)
+
+
+_FUSED_STEPS = {k: _step_program(k) for k in ("sum", "max", "read")}
+
+
+def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
+               ts: jax.Array, weights: jax.Array, fire: jax.Array,
+               valid: jax.Array, *, kind: str = "sum") -> FusedStep:
+    """One fused batch over the resident working set.
+
+    ``kind`` picks the operator compute: ``sum`` (count is sum of ones),
+    ``max``, or ``read`` (no state update, read-only enrichment).  Each
+    kind is its own jitted program named after it (``fused_step_sum``,
+    ``fused_step_max``, ``fused_step_read``), so a device trace tells
+    the planes of one engine apart; ``fused_step.lower(..., kind=)``
+    lowers the kind's program.  ``weights`` is ``[B, V]``; ``fire``
+    lanes read the pane without updating it.
+
+    Duplicate keys in one batch compose EXACTLY as the interpreted
+    sequential loop: lane i's ``new_vals`` folds in every earlier
+    same-key update lane (lower-triangular mask), and the scatter's
+    last-write-wins grid order leaves the final composed value in the
+    pool.  The batching contract (streaming/fused.py) never mixes a fire
+    lane and an update lane of the same key in one batch.
+
+    Miss lanes are NOT admitted here — the host parks their tuples and
+    admissions arrive later through ``fused_admit`` (the asynchronous
+    fetch path, DESIGN.md §2) — so a miss lane's only trace is its tally.
+    """
+    return _FUSED_STEPS[kind](state, pages, keys, ts, weights, fire, valid)
+
+
+def _lower_fused_step(*args, kind: str = "sum"):
+    return _FUSED_STEPS[kind].lower(*args)
+
+
+fused_step.lower = _lower_fused_step
 
 
 @jax.jit

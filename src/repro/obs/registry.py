@@ -354,6 +354,13 @@ METRIC_CATALOG: Dict[str, str] = {
     "engine.<op>.prefetch.suppress_unused":
         "suppressions never followed by an in-horizon access (hint would "
         "have been wasted)",
+    # watermark hold (§10): keyed operators send a watermark on only
+    # once no parked or ready tuple is at or behind it
+    "engine.<op>.wm.held": "watermarks held behind parked tuples",
+    "engine.<op>.wm.hold_s":
+        "simulated seconds watermarks were held, summed",
+    "engine.<op>.late_dropped":
+        "tuples a windowed or join operator dropped as late",
     # fused device hot path (§14): per-batch device tallies rolled up
     # host-side after each launch
     "engine.<op>.fused.batches": "fused device batches launched",

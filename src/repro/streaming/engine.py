@@ -850,6 +850,14 @@ class StatefulOp(Operator):
         # CheckpointCoordinator is attached (the coordinator trims it at
         # each completed epoch).
         self.hint_log: List[List] = [[] for _ in range(parallelism)]
+        # watermark hold (DESIGN.md §10): per subtask, the watermarks not
+        # yet sent downstream because a tuple at or behind them is still
+        # parked or ready, oldest first, each with the sim time it
+        # arrived; counted (held, simulated seconds held) for §12
+        self._held: List[deque] = [deque() for _ in range(parallelism)]
+        self.wm_held = 0
+        self.wm_hold_s = 0.0
+        self._span_release = f"stream.{name}.wm_release"
 
     def _attach_obs(self) -> None:
         """Wire the recorder into every TAC and the access-latency
@@ -969,6 +977,8 @@ class StatefulOp(Operator):
             else:
                 keep.append(tup)
         self.ready[src] = keep
+        if self._held[src]:
+            self._release_watermarks(src)
         # authoritative backend partition moves off the tuple path
         self.backends[dst_sub].import_keys(
             self.backends[src].export_keys(in_shard))
@@ -995,6 +1005,58 @@ class StatefulOp(Operator):
         pending = self.shard_pending.pop(shard, [])
         if pending:
             self.deliver_batch(dst_sub, pending)
+
+    # ------------------------------------------------------ watermark hold
+    def emit_watermark(self, sub: int, wm: float) -> None:
+        """Send ``wm`` downstream only once everything is emitted for the
+        tuples at or behind it that this subtask took in: a tuple parked
+        on a fetch, or resumed and not yet processed, holds it
+        (DESIGN.md §10).  With nothing parked it goes at once."""
+        held = self._held[sub]
+        if not (held or self.waiting[sub] or self.ready[sub]):
+            super().emit_watermark(sub, wm)
+            return
+        held.append((wm, self.sim.t))
+        self._release_watermarks(sub)
+        if held and held[-1][0] == wm:
+            self.wm_held += 1
+
+    def _holds_watermark(self, tup: Tuple_) -> bool:
+        """Hook: does this parked or ready tuple hold watermarks?  Every
+        tuple the operator took in does; windowed subclasses exempt
+        their own FIREs, which follow the watermark that made them."""
+        return True
+
+    def _parked_low(self, sub: int) -> float:
+        """The least event time of a parked or ready tuple that holds
+        watermarks, else inf."""
+        low = float("inf")
+        for parked in self.waiting[sub].values():
+            for t in parked:
+                if t.ts < low and self._holds_watermark(t):
+                    low = t.ts
+        for t in self.ready[sub]:
+            if t.ts < low and self._holds_watermark(t):
+                low = t.ts
+        return low
+
+    def _release_watermarks(self, sub: int) -> None:
+        """Send the newest held watermark that no parked or ready tuple
+        is at or behind, dropping the older ones it covers."""
+        held = self._held[sub]
+        low = self._parked_low(sub)
+        if held[0][0] >= low:
+            return
+        spans = self.engine.spans
+        if spans.enabled:
+            spans.enter(self._span_release)
+        now = self.sim.t
+        while held and held[0][0] < low:
+            wm, t0 = held.popleft()
+            self.wm_hold_s += now - t0
+        super().emit_watermark(sub, wm)
+        if spans.enabled:
+            spans.exit()
 
     def _on_hint(self, sub: int, h: Hint) -> float:
         mgr = self.managers[sub]
@@ -1154,6 +1216,8 @@ class StatefulOp(Operator):
             self._park_t[sub].pop(req.key, None)
             for tup in self.waiting[sub].pop(req.key, []):
                 self._on_dead_parked(sub, tup)
+            if self._held[sub]:
+                self._release_watermarks(sub)
         else:
             state, _ = self.backends[sub].fetch(req.key, self.state_size)
             wb = self.wb_pending[sub].get(req.key)
@@ -1270,6 +1334,8 @@ class StatefulOp(Operator):
                 svc = self._shard_guard(sub, tup)
             if svc is None:
                 svc = self.handle_parked(sub, tup)
+            if self._held[sub]:
+                self._release_watermarks(sub)
             self.busy_time[sub] += svc
             self.sim.after(svc, self._finish, sub)
             return
@@ -1587,6 +1653,7 @@ class StatefulOp(Operator):
         self.io_q = [deque() for _ in range(p)]
         self.io_free = [self.io_workers] * p
         self.miss_reported = [False] * p
+        self._held = [deque() for _ in range(p)]
         self.shard_pending.clear()
         if self.shards is not None:
             self.shards.migrating.clear()
@@ -2113,6 +2180,11 @@ class Engine:
                 sum(m.hints_duplicate for m in op.managers))
             r.counter(f"{pre}.prefetch.hits").set(
                 sum(m.prefetch_hits for m in op.managers))
+            r.counter(f"{pre}.wm.held").set(op.wm_held)
+            r.gauge(f"{pre}.wm.hold_s").set(op.wm_hold_s)
+            late = getattr(op, "late_dropped", None)
+            if late is not None:
+                r.counter(f"{pre}.late_dropped").set(late)
             ev: Dict[str, int] = {}
             for c in op.caches:
                 for k, v in getattr(c, "eviction_block",

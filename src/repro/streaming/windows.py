@@ -111,7 +111,11 @@ class WindowedStatefulOp(StatefulOp):
     before firing is refetched — synchronously in ``sync`` mode, via the
     I/O lanes otherwise), then ``emit_fn`` produces the result tuple with
     ``ingest_t`` = the fire-eligible time, so sink latency measures
-    watermark-to-delivery.
+    watermark-to-delivery.  ``latency_from_end=True`` stamps each fire
+    with its window's end instead: where the source stamps events with
+    the simulated clock (YSB), sink latency is then window end to
+    delivery, the latency YSB publishes, which takes in the watermark's
+    way through the pipeline (held behind parked fetches included).
 
     Late tuples (window end + ``allowed_lateness`` behind the watermark)
     are dropped and counted.  Tuples for a FIRED window still inside the
@@ -126,7 +130,8 @@ class WindowedStatefulOp(StatefulOp):
                  emit_fn: Callable[[Any, int, float, Any], Any],
                  backend_model, cache_capacity: int,
                  allowed_lateness: float = 0.0, late_policy: str = "drop",
-                 out_size: int = 200, **kw):
+                 out_size: int = 200, latency_from_end: bool = False,
+                 **kw):
         if late_policy not in ("drop", "update"):
             raise ValueError(f"late_policy {late_policy!r}")
         if late_policy == "update" and allowed_lateness <= 0:
@@ -150,6 +155,7 @@ class WindowedStatefulOp(StatefulOp):
         self.allowed_lateness = float(allowed_lateness)
         self.late_policy = late_policy
         self.out_size = out_size
+        self.latency_from_end = latency_from_end
         # wid -> {"keys": live base keys, "fired": watermark crossed the
         # end, "fired_keys": keys whose FIRE was scheduled (or that
         # arrived late and must not fire)}, per subtask.  Fired state is
@@ -373,9 +379,10 @@ class WindowedStatefulOp(StatefulOp):
                 # in by a migration after this window already fired here
                 meta["fired"] = True
                 meta["fired_keys"] |= to_fire
+                ingest = end if self.latency_from_end else now
                 for base in to_fire:
                     fire_batch.append(Tuple_(end, WindowKey(base, wid),
-                                             FIRE, 32, now))
+                                             FIRE, 32, ingest))
             elif not meta["fired"] and end <= wm:
                 meta["fired"] = True            # crossed with nothing live
             elif meta["fired"] and self.allowed_lateness > 0 \
@@ -400,6 +407,9 @@ class WindowedStatefulOp(StatefulOp):
             # fused and interpreted planes run at different speeds
             self.queues[sub].extendleft(reversed(fire_batch))
             self._kick(sub)
+
+    def _holds_watermark(self, tup: Tuple_) -> bool:
+        return tup.payload is not FIRE
 
     def handle_parked(self, sub: int, tup: Tuple_) -> float:
         svc = super().handle_parked(sub, tup)

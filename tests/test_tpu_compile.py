@@ -5,7 +5,8 @@ the TPU compiler refuses what interpret mode accepts (blocks that break
 the (8, 128) tiling, kernels that outgrow VMEM, programs that outgrow
 HBM).  Each program is lowered as the engine calls it, so the Pallas
 kernels are chosen by the platform the program is lowered for, not by
-any flag.  Widths: the planes ``chip_smoke.py`` runs, and 2^20 slots.
+any flag.  Widths: the planes ``chip_smoke.py`` runs, the campaign
+count's, and 2^20 slots.
 """
 import os
 import sys
@@ -21,7 +22,11 @@ from repro.core import tac_jax  # noqa: E402
 from repro.kernels.tac_probe.ops import tac_probe_gather  # noqa: E402
 
 B = chip_smoke.BATCH
-WIDTHS = sorted({chip_smoke.Q5_SLOTS, chip_smoke.YSB_SLOTS, 2 ** 20})
+# the campaign count's plane of YSB as published
+# (bench/configs/ysb-campaign.json)
+WINDOW_SLOTS = 1024
+WIDTHS = sorted({chip_smoke.Q5_SLOTS, chip_smoke.YSB_SLOTS, WINDOW_SLOTS,
+                 2 ** 20})
 HBM = 16 * 2 ** 30                       # one v5e chip
 
 
