@@ -452,18 +452,111 @@ def _scatter(slots, blocks, pages):
         default=_scatter_ref)
 
 
-class FusedStep(NamedTuple):
-    state: TACState
-    pages: jax.Array
-    hit: jax.Array        # [B] bool   (padding lanes forced False)
-    slots: jax.Array      # [B] int32  flat slot; scratch for miss/padding
-    new_vals: jax.Array   # [B, V]     value AFTER this lane's update,
-    #                       prefix-composed over earlier same-key lanes
-    present: jax.Array    # [B] bool   presence flag after this lane
-    tallies: jax.Array    # [2] int32  (hits, misses) over valid lanes
+# Host <-> device interface (§14): on the chip each host array a call
+# hands the device, and each result array the host reads back, costs a
+# fixed fraction of a millisecond whatever its size, against tens of
+# microseconds of device work per call.  So every fused entry point packs
+# its per-lane inputs into ONE int32 slab on the host, and ``fused_step``
+# returns its per-lane outputs as ONE int32 slab the host reads in one
+# transfer.  float32 columns cross as their bit patterns
+# (``ndarray.view`` on the host, ``lax.bitcast_convert_type`` in the
+# program), bools as 0/1: every value arrives bit-exact.  The public
+# entry points keep their per-array signatures (and ``.lower``): the
+# packing is a host-side wrapper around one jitted program each.
+
+def _bits(x) -> np.ndarray:
+    """float32 values as their int32 bit patterns (a view, not a cast)."""
+    return np.asarray(x, np.float32).view(np.int32)
+
+
+def _pack(*cols) -> np.ndarray:
+    """Per-lane columns ([B] or [B, k]) side by side in one int32 slab."""
+    return np.column_stack(cols).astype(np.int32, copy=False)
+
+
+def _slab_spec(like, width: int) -> jax.ShapeDtypeStruct:
+    """The slab an entry point's ``.lower`` stands in for its columns:
+    rows from ``like``, on ``like``'s sharding where it has one."""
+    return jax.ShapeDtypeStruct((like.shape[0], width), jnp.int32,
+                                sharding=getattr(like, "sharding", None))
+
+
+def _f32(cols) -> jax.Array:
+    return jax.lax.bitcast_convert_type(cols, jnp.float32)
+
+
+def _program(name: str):
+    """jit ``fn`` as the one device module ``jit_<name>``."""
+    def named(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+    return named
+
+
+def _unpack_step_out(out: np.ndarray) -> dict:
+    """Decode ``fused_step``'s host slab ``[B + 1, V + 3]``: per lane
+    ``hit, slots, present, new_vals bits``; ``tallies`` in the last row."""
+    lanes = out[:-1]
+    return {"hit": lanes[:, 0] != 0, "slots": lanes[:, 1],
+            "present": lanes[:, 2] != 0,
+            "new_vals": lanes[:, 3:].view(np.float32),
+            "tallies": out[-1, :2]}
+
+
+@jax.tree_util.register_pytree_node_class
+class FusedStep:
+    """One fused batch's results.  ``state`` and ``pages`` stay on the
+    device; ``out`` is the per-lane output slab, still on the device
+    until the first read of a lane field, which copies it to the host
+    ONCE (``read``) and decodes:
+
+      hit       [B] bool   (padding lanes forced False)
+      slots     [B] int32  flat slot; scratch for miss/padding
+      new_vals  [B, V]     value AFTER this lane's update,
+                           prefix-composed over earlier same-key lanes
+      present   [B] bool   presence flag after this lane
+      tallies   [2] int32  (hits, misses) over valid lanes
+    """
+    FIELDS = ("hit", "slots", "new_vals", "present", "tallies")
+
+    def __init__(self, state: TACState, pages: jax.Array, out: jax.Array,
+                 host: dict = None):
+        self.state, self.pages, self.out = state, pages, out
+        self._host = host
+
+    def read(self) -> dict:
+        """The lane fields on the host: one transfer, decoded once."""
+        if self._host is None:
+            self._host = _unpack_step_out(np.asarray(self.out))
+        return self._host
+
+    hit = property(lambda self: self.read()["hit"])
+    slots = property(lambda self: self.read()["slots"])
+    new_vals = property(lambda self: self.read()["new_vals"])
+    present = property(lambda self: self.read()["present"])
+    tallies = property(lambda self: self.read()["tallies"])
+
+    def _replace(self, **kw) -> "FusedStep":
+        """A copy with fields replaced, as ``NamedTuple._replace``; a
+        replaced lane field reads back as given."""
+        state = kw.pop("state", self.state)
+        pages = kw.pop("pages", self.pages)
+        if set(kw) - set(self.FIELDS):
+            raise ValueError(f"no FusedStep fields {sorted(kw)}")
+        host = {**self.read(), **kw} if kw else self._host
+        return FusedStep(state, pages, self.out, host)
+
+    def tree_flatten(self):
+        return (self.state, self.pages, self.out, self._host), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
 
 
 def _fused_step(state, pages, keys, ts, weights, fire, valid, kind):
+    """The step's device compute over per-lane arrays; returns
+    ``(state, pages, hit, slots, new_vals, present, tallies)``."""
     B = keys.shape[0]
     n_buckets, ways = state.keys.shape
     trash = pages.shape[0] - 1
@@ -511,19 +604,38 @@ def _fused_step(state, pages, keys, ts, weights, fire, valid, kind):
         dirty = d_int > 0
     tallies = jnp.stack([hit.sum(), (valid & ~hit).sum()]
                         ).astype(jnp.int32)
-    return FusedStep(state._replace(ts=new_ts, dirty=dirty), pages,
-                     hit, slots, new_v, present, tallies)
+    return (state._replace(ts=new_ts, dirty=dirty), pages,
+            hit, slots, new_v, present, tallies)
 
 
 def _step_program(kind: str):
-    def step(state, pages, keys, ts, weights, fire, valid):
-        return _fused_step(state, pages, keys, ts, weights, fire, valid,
-                           kind)
-    step.__name__ = step.__qualname__ = f"fused_step_{kind}"
-    return jax.jit(step)
+    @_program(f"fused_step_{kind}")
+    def step(state, pages, slab):
+        # slab [B, V + 4]: keys | ts bits | weights bits (V) | fire | valid
+        V = slab.shape[1] - 4
+        state, pages, hit, slots, new_v, present, tallies = _fused_step(
+            state, pages, slab[:, 0], _f32(slab[:, 1]),
+            _f32(slab[:, 2:V + 2]), slab[:, V + 2] != 0,
+            slab[:, V + 3] != 0, kind)
+        # out [B + 1, V + 3]: hit | slots | present | new_vals bits (V),
+        # then one row holding the tallies
+        lanes = jnp.concatenate(
+            [hit[:, None].astype(jnp.int32), slots[:, None],
+             present[:, None].astype(jnp.int32),
+             jax.lax.bitcast_convert_type(new_v.astype(jnp.float32),
+                                          jnp.int32)], axis=1)
+        tail = jnp.zeros((1, V + 3), jnp.int32).at[0, :2].set(tallies)
+        return state, pages, jnp.concatenate([lanes, tail], axis=0)
+    return step
 
 
 _FUSED_STEPS = {k: _step_program(k) for k in ("sum", "max", "read")}
+
+
+def _step_slab(keys, ts, weights, fire, valid) -> np.ndarray:
+    B = np.shape(keys)[0]
+    return _pack(keys, _bits(ts), _bits(weights).reshape(B, -1), fire,
+                 valid)
 
 
 def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
@@ -537,7 +649,9 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
     ``fused_step_max``, ``fused_step_read``), so a device trace tells
     the planes of one engine apart; ``fused_step.lower(..., kind=)``
     lowers the kind's program.  ``weights`` is ``[B, V]``; ``fire``
-    lanes read the pane without updating it.
+    lanes read the pane without updating it.  The five lane arrays go to
+    the device as one slab and the lane results come back as one
+    (``FusedStep``).
 
     Duplicate keys in one batch compose EXACTLY as the interpreted
     sequential loop: lane i's ``new_vals`` folds in every earlier
@@ -550,27 +664,21 @@ def fused_step(state: TACState, pages: jax.Array, keys: jax.Array,
     admissions arrive later through ``fused_admit`` (the asynchronous
     fetch path, DESIGN.md §2) — so a miss lane's only trace is its tally.
     """
-    return _FUSED_STEPS[kind](state, pages, keys, ts, weights, fire, valid)
+    return FusedStep(*_FUSED_STEPS[kind](
+        state, pages, _step_slab(keys, ts, weights, fire, valid)))
 
 
-def _lower_fused_step(*args, kind: str = "sum"):
-    return _FUSED_STEPS[kind].lower(*args)
+def _lower_fused_step(state, pages, keys, ts, weights, fire, valid, *,
+                      kind: str = "sum"):
+    return _FUSED_STEPS[kind].lower(
+        state, pages, _slab_spec(keys, weights.shape[1] + 4))
 
 
 fused_step.lower = _lower_fused_step
 
 
-@jax.jit
-def fused_admit(state: TACState, pages: jax.Array, slots: jax.Array,
-                keys: jax.Array, ts: jax.Array, rows: jax.Array,
-                present: jax.Array, dirty: jax.Array):
-    """Admit at HOST-CHOSEN slots (the shadow directory resolved victims
-    and free slots; a slot may repeat only as an IDENTICAL padding
-    duplicate of an earlier lane — chunked flushes pad to fixed jit
-    shapes that way).  Gathers the pre-overwrite victim rows first — a
-    dirty victim's value feeds the eviction buffer for asynchronous
-    write-back — then scatters the new rows and updates the device
-    directory.  Returns ``(state, pages, victim_rows [B, 1, V+1])``."""
+def _fused_admit(state, pages, slots, keys, ts, rows, present, dirty):
+    """The admit's device compute over per-lane arrays."""
     n_buckets, ways = state.keys.shape
     b, w = slots // ways, slots % ways
     victim_rows = _gather(slots, pages)
@@ -589,14 +697,41 @@ def fused_admit(state: TACState, pages: jax.Array, slots: jax.Array,
     return st, new_pages, victim_rows
 
 
-@jax.jit
-def drop_slots(state: TACState, slots: jax.Array,
-               valid: jax.Array) -> TACState:
-    """Clear directory entries at host-chosen slots (window-pane purges,
-    drops).  Padding lanes (``valid`` False) alias slot 0, so the
-    clears use masked min/max scatters that are idempotent no-ops for
-    them.  Pool rows are left stale: a cleared slot can no longer be
-    probed, and the next ``fused_admit`` overwrites the row."""
+@_program("fused_admit")
+def _admit_program(state: TACState, pages: jax.Array, slab: jax.Array):
+    # slab [B, V + 5]: slots | keys | ts bits | present | dirty | rows (V)
+    return _fused_admit(state, pages, slab[:, 0], slab[:, 1],
+                        _f32(slab[:, 2]), _f32(slab[:, 5:]),
+                        slab[:, 3] != 0, slab[:, 4] != 0)
+
+
+def fused_admit(state: TACState, pages: jax.Array, slots: jax.Array,
+                keys: jax.Array, ts: jax.Array, rows: jax.Array,
+                present: jax.Array, dirty: jax.Array):
+    """Admit at HOST-CHOSEN slots (the shadow directory resolved victims
+    and free slots; a slot may repeat only as an IDENTICAL padding
+    duplicate of an earlier lane — chunked flushes pad to fixed jit
+    shapes that way).  Gathers the pre-overwrite victim rows first — a
+    dirty victim's value feeds the eviction buffer for asynchronous
+    write-back — then scatters the new rows and updates the device
+    directory.  The six lane arrays go to the device as one slab.
+    Returns ``(state, pages, victim_rows [B, 1, V+1])``."""
+    B = np.shape(slots)[0]
+    return _admit_program(state, pages, _pack(
+        slots, keys, _bits(ts), present, dirty, _bits(rows).reshape(B, -1)))
+
+
+def _lower_fused_admit(state, pages, slots, keys, ts, rows, present,
+                       dirty):
+    return _admit_program.lower(state, pages,
+                                _slab_spec(slots, rows.shape[1] + 5))
+
+
+fused_admit.lower = _lower_fused_admit
+
+
+def _drop_slots(state, slots, valid):
+    """The directory clear's device compute over per-lane arrays."""
     ways = state.keys.shape[1]
     b, w = slots // ways, slots % ways
     imax = jnp.iinfo(jnp.int32).max
@@ -607,6 +742,30 @@ def drop_slots(state: TACState, slots: jax.Array,
     d_int = state.dirty.astype(jnp.int32).at[b, w].min(
         jnp.where(valid, 0, 1))
     return state._replace(keys=keys, ts=ts, dirty=d_int > 0)
+
+
+@_program("drop_slots")
+def _drop_program(state: TACState, slab: jax.Array) -> TACState:
+    # slab [B, 2]: slots | valid
+    return _drop_slots(state, slab[:, 0], slab[:, 1] != 0)
+
+
+def drop_slots(state: TACState, slots: jax.Array,
+               valid: jax.Array) -> TACState:
+    """Clear directory entries at host-chosen slots (window-pane purges,
+    drops).  Padding lanes (``valid`` False) alias slot 0, so the
+    clears use masked min/max scatters that are idempotent no-ops for
+    them.  Pool rows are left stale: a cleared slot can no longer be
+    probed, and the next ``fused_admit`` overwrites the row.  Both lane
+    arrays go to the device as one slab."""
+    return _drop_program(state, _pack(slots, valid))
+
+
+def _lower_drop_slots(state, slots, valid):
+    return _drop_program.lower(state, _slab_spec(slots, 2))
+
+
+drop_slots.lower = _lower_drop_slots
 
 
 @jax.jit

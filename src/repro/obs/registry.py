@@ -378,6 +378,10 @@ METRIC_CATALOG: Dict[str, str] = {
     "engine.<op>.fused.calls.<program>":
         "device calls by program: fused_step|fused_admit|gather_rows|"
         "drop_slots",
+    "engine.<op>.fused.transfers.<dir>":
+        "host arrays handed to (to_device) and read back from (to_host) "
+        "device calls: one lane slab per call in, one per fused_step out, "
+        "plus whole-pool reads",
     "engine.<op>.fused.victim_reads":
         "dirty victims with no queued row, read from the value shadow",
     "engine.<op>.fused.shadow_reads":

@@ -2074,6 +2074,8 @@ class Engine:
                                                 for c in fp),
                         "calls": {prog: sum(c.calls[prog] for c in fp)
                                   for prog in FusedPlane.PROGRAMS},
+                        "transfers": {d: sum(c.transfers[d] for c in fp)
+                                      for d in FusedPlane.TRANSFERS},
                         "victim_reads": sum(c.victim_reads for c in fp),
                         "shadow_reads": sum(c.shadow_reads for c in fp),
                     }
@@ -2210,6 +2212,9 @@ class Engine:
                 for prog in FusedPlane.PROGRAMS:
                     r.counter(f"{pre}.fused.calls.{prog}").set(
                         sum(c.calls[prog] for c in fp))
+                for d in FusedPlane.TRANSFERS:
+                    r.counter(f"{pre}.fused.transfers.{d}").set(
+                        sum(c.transfers[d] for c in fp))
                 r.counter(f"{pre}.fused.victim_reads").set(
                     sum(c.victim_reads for c in fp))
                 r.counter(f"{pre}.fused.shadow_reads").set(

@@ -147,6 +147,7 @@ class FusedPlane:
     DROP_W = 32               # fixed width of the batched directory clear
 
     PROGRAMS = ("fused_step", "fused_admit", "gather_rows", "drop_slots")
+    TRANSFERS = ("to_device", "to_host")
 
     def __init__(self, capacity: int, entry_size: int, spec: FusedSpec,
                  deadline_aware: bool = False, batch: int = 64,
@@ -188,8 +189,8 @@ class FusedPlane:
         self._gen = 0
         self._pending_drops: List[int] = []
         # deferred admissions (§14): misses arrive one completion at a
-        # time from the I/O plane, but a per-admit device call costs
-        # ~10x the jit argument path.  _place queues the row host-side
+        # time from the I/O plane, but each device call costs about a
+        # millisecond of fixed overhead.  _place queues the row host-side
         # (slot-keyed, so a re-write before the flush supersedes in
         # place) and _flush_admits lands the whole backlog in chunked
         # fused_admit calls right before the next device op needs it.
@@ -228,9 +229,11 @@ class FusedPlane:
         self.device_hits = 0
         self.device_misses = 0
         self.device_conflicts = 0
-        # one per device call, by program; dirty victims read back; rows
-        # served from the value shadow (victims and slot reads)
+        # one per device call, by program; host arrays handed to and
+        # read back from device calls (one slab each way, §14); dirty
+        # victims read back; rows served from the value shadow
         self.calls: Dict[str, int] = dict.fromkeys(self.PROGRAMS, 0)
+        self.transfers: Dict[str, int] = dict.fromkeys(self.TRANSFERS, 0)
         self.victim_reads = 0
         self.shadow_reads = 0
 
@@ -260,9 +263,11 @@ class FusedPlane:
             valid = np.zeros(self.DROP_W, bool)
             slots[:len(chunk)] = chunk
             valid[:len(chunk)] = True
-            # np arrays go straight into the jitted call: jit's argument
-            # path converts in ~us, an explicit device put costs ~100x
+            # np arrays go straight into the call, which packs them into
+            # one slab: each array crossing to the chip costs a fraction
+            # of a millisecond, whatever its size
             self.calls["drop_slots"] += 1
+            self.transfers["to_device"] += 1
             self.tac = self._tj.drop_slots(self.tac, slots, valid)
         if spans.enabled:
             spans.exit()
@@ -297,6 +302,7 @@ class FusedPlane:
             pres = np.asarray([r[3] for r in rs], bool)
             dirty = np.asarray([r[4] for r in rs], bool)
             self.calls["fused_admit"] += 1
+            self.transfers["to_device"] += 1
             self.tac, self.pages, _ = self._tj.fused_admit(
                 self.tac, self.pages, slots, kids, ts, rows, pres,
                 dirty)
@@ -548,6 +554,7 @@ class FusedPlane:
         if spans.enabled:
             spans.enter("stream.fused.pool_read")
         pool = np.asarray(self.pages)
+        self.transfers["to_host"] += 1
         if spans.enabled:
             spans.exit()
         return pool
@@ -637,7 +644,7 @@ class FusedPlane:
 
         With spans on, the call splits into ``stream.fused.stage``
         (flushes and staging), ``dispatch`` (the jitted call),
-        ``readback`` (the host blocked on the outputs) and ``shadow``.
+        ``readback`` (the host blocked on the output slab) and ``shadow``.
         """
         n = len(lanes)
         B = self.batch
@@ -667,17 +674,20 @@ class FusedPlane:
         if on:
             spans.switch("stream.fused.dispatch")
         self.calls["fused_step"] += 1
+        self.transfers["to_device"] += 1
         out = self._tj.fused_step(self.tac, self.pages, keys, ts32,
                                   weights, fire, valid,
                                   kind=self.spec.kind)
         self.tac, self.pages = out.state, out.pages
         if on:
             spans.switch("stream.fused.readback")
-        hit = np.asarray(out.hit)[:n]
-        slots = np.asarray(out.slots)[:n]
-        new_vals = np.asarray(out.new_vals)[:n]
-        present = np.asarray(out.present)[:n]
-        tallies = np.asarray(out.tallies)
+        res = out.read()
+        self.transfers["to_host"] += 1
+        hit = np.asarray(res["hit"])[:n]
+        slots = np.asarray(res["slots"])[:n]
+        new_vals = np.asarray(res["new_vals"])[:n]
+        present = np.asarray(res["present"])[:n]
+        tallies = np.asarray(res["tallies"])
         if on:
             spans.switch("stream.fused.shadow")
         self.batches += 1

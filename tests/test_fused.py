@@ -440,6 +440,29 @@ def test_fusedplane_batch_step_composes_duplicates():
     assert isinstance(res.new_vals, np.ndarray)
 
 
+def test_batch_step_is_one_transfer_each_way():
+    """Each device call hands the device one host array (the lane slab),
+    and a batch step reads one back: queued drops and admissions land in
+    their own calls first, one array each."""
+    spec = count_spec()
+    plane = FusedPlane(4 * 8, 8, spec, batch=8)
+    for i, k in enumerate("abc"):
+        plane.insert(k, i, 1.0, dirty=False)
+    plane.drop("c")
+    lanes = [Lane(k, 2.0, spec.weight(None), False, False, None)
+             for k in "aab"]
+    for expect in ({"fused_step": 1, "fused_admit": 1, "drop_slots": 1},
+                   {"fused_step": 1}):
+        calls, moved = dict(plane.calls), dict(plane.transfers)
+        plane.batch_step(lanes)
+        d_calls = {p: n - calls[p] for p, n in plane.calls.items()
+                   if n != calls[p]}
+        assert d_calls == expect
+        assert plane.transfers["to_device"] - moved["to_device"] == \
+            sum(expect.values())
+        assert plane.transfers["to_host"] - moved["to_host"] == 1
+
+
 def test_value_shadow_takes_the_last_update_lane_to_the_victim():
     """Two update lanes of one key in one batch, then that key's
     eviction: the victim written back carries the LAST lane's value
@@ -470,8 +493,15 @@ def test_value_shadow_takes_the_last_update_lane_to_the_victim():
 
 
 def _query_engine(query, seed):
-    if query == "ysb":
+    if query.startswith("ysb"):
         from repro.streaming.ysb import YSBConfig, build_ysb
+        if query == "ysb-campaign":       # YSB as published
+            cfg = YSBConfig(rate=2_000.0, n_ads=5_000, seed=seed,
+                            watermark_interval=0.05, oo_bound=0.0)
+            return build_ysb("tac", "prefetch", cfg, fused=True,
+                             fused_batch=64, cache_entries=256,
+                             parallelism=1, source_parallelism=1,
+                             campaign_window_s=0.5)
         cfg = YSBConfig(rate=2_000.0, n_ads=5_000, seed=seed)
         return build_ysb("tac", "prefetch", cfg, fused=True, fused_batch=64,
                          cache_entries=256, parallelism=1,
@@ -552,3 +582,31 @@ def test_fetch_completion_never_rolls_back_a_resident_entry(fused):
     op._io_done(0, _IOReq("prefetch", "k", 4.0), 1e-4)
     assert op.caches[0].lookup("k", 2.0) == 6
     assert op.caches[0].flush_dirty()[0].state == 6
+
+
+@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign"])
+def test_one_transfer_each_way_per_device_call_in_query_runs(query):
+    """Small fused runs of each deployment the benchmark runs (q5's
+    windowed count, YSB's read join, YSB as published with its campaign
+    count): every plane hands the device one array per call and reads
+    one back per batch step."""
+    eng = _query_engine(query, 13)
+    planes = [c for op in eng.operators.values()
+              if isinstance(op, StatefulOp) for c in op.caches
+              if isinstance(c, FusedPlane)]
+    assert len(planes) == (2 if query == "ysb-campaign" else 1)
+    eng.run(duration=2.0)
+    for plane in planes:
+        assert plane.calls["fused_step"] > 10
+        assert plane.transfers == {
+            "to_device": sum(plane.calls.values()),
+            "to_host": plane.calls["fused_step"]}
+    # rolled into the §12 registry beside the calls, per operator
+    eng._sync_registry()
+    reg = eng.registry.snapshot()
+    for name, op in eng.operators.items():
+        fp = [c for c in getattr(op, "caches", ()) if c in planes]
+        for d in FusedPlane.TRANSFERS:
+            if fp:
+                assert reg[f"engine.{name}.fused.transfers.{d}"] == \
+                    sum(c.transfers[d] for c in fp)

@@ -270,3 +270,117 @@ def test_fused_step_sum_exact_for_fractional_weights():
                                rtol=1e-6, atol=1e-4)
     np.testing.assert_allclose(np.asarray(out.pages)[0, 0, 1:], ref[-1],
                                rtol=1e-6, atol=1e-4)
+
+
+# ------------------------------------- fused entry points: one slab each way
+def _seeded_pool(W, V):
+    """A directory of W slots holding keys 0..5 at slots 0..5, one of them
+    absent (never written), with fractional and negative values."""
+    from repro.core import tac_jax
+    state = tac_jax.init(1, W, 1)
+    pages = jnp.zeros((W + 1, 1, V + 1), jnp.float32)
+    seed = (np.arange(6 * V, dtype=np.float32).reshape(6, V) - 4.5) / 3
+    return tac_jax.fused_admit(
+        state, pages, np.arange(6, dtype=np.int32),
+        np.arange(6, dtype=np.int32),
+        np.asarray([0.5, -np.inf, 2.0, 1.0, 3.5, 0.25], np.float32), seed,
+        np.asarray([1, 1, 0, 1, 1, 1], bool), np.zeros(6, bool))[:2]
+
+
+def _bits_equal(a, b):
+    a, b = np.ascontiguousarray(a), np.ascontiguousarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    assert np.array_equal(a.view(np.uint8), b.view(np.uint8))
+
+
+def _slab_and_per_array(program, W=16, V=2):
+    """(entry point's outputs, the same compute jitted with one argument
+    per lane array) as flat lists of arrays, over lanes that cover
+    padding (PAD_KEY, invalid or duplicate-padded), -inf timestamps,
+    fractional and negative values, fire lanes and duplicate keys."""
+    import functools
+    from repro.core import tac_jax
+    state, pages = _seeded_pool(W, V)
+    rng = np.random.RandomState(5)
+    if program.startswith("fused_step"):
+        kind = program.split(".")[1]
+        keys = np.asarray([3, 3, 5, 9, 3, 4, 5, 0, 1, -2, -2, 3], np.int32)
+        B = len(keys)
+        ts = np.asarray([1.5, -np.inf, 2.0, 7.25, 3.0, -np.inf, 0.5, 4.0,
+                         1.0, 0.0, 0.0, 2.5], np.float32)
+        w = (rng.randn(B, V) * 3 + 1 / 3).astype(np.float32)
+        fire = np.isin(keys, (4, 0))
+        valid = keys != -2
+        args = (keys, ts, w, fire, valid)
+        out = tac_jax.fused_step(state, pages, *args, kind=kind)
+        got = [out.state, out.pages, out.hit, out.slots, out.new_vals,
+               out.present, out.tallies]
+        ref = jax.jit(functools.partial(tac_jax._fused_step, kind=kind))(
+            state, pages, *map(jnp.asarray, args))
+        assert out.hit.any() and not out.hit.all()
+    elif program == "fused_admit":
+        n, Wc = 5, 8                   # a chunk padded by its first record
+        slots = np.asarray([7, 2, 9, 0, 12] + [7] * (Wc - n), np.int32)
+        keys = np.asarray([40, 41, 42, 43, 44] + [40] * (Wc - n), np.int32)
+        ts = np.asarray([2.5, -np.inf, 0.75, 9.0, 1.0] + [2.5] * (Wc - n),
+                        np.float32)
+        rows = (rng.randn(Wc, V) - 1 / 7).astype(np.float32)
+        rows[n:] = rows[0]
+        present = np.asarray([1, 0, 1, 1, 1] + [1] * (Wc - n), bool)
+        dirty = np.asarray([0, 1, 1, 0, 1] + [0] * (Wc - n), bool)
+        args = (slots, keys, ts, rows, present, dirty)
+        got = tac_jax.fused_admit(state, pages, *args)
+        ref = jax.jit(tac_jax._fused_admit)(state, pages,
+                                            *map(jnp.asarray, args))
+    else:
+        slots = np.asarray([4, 1, 0, 0, 0, 0], np.int32)   # 0: padding
+        valid = np.asarray([1, 1, 0, 0, 0, 0], bool)
+        got = tac_jax.drop_slots(state, slots, valid)
+        ref = jax.jit(tac_jax._drop_slots)(state, jnp.asarray(slots),
+                                           jnp.asarray(valid))
+    return jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(ref)
+
+
+@pytest.mark.parametrize("program", ["fused_step.sum", "fused_step.max",
+                                     "fused_step.read", "fused_admit",
+                                     "drop_slots"])
+def test_slab_entry_point_is_bit_identical_to_per_array(program):
+    """Packing the lanes into one int32 slab (floats by bit pattern) and
+    unpacking the step's one output slab changes no bit of any result:
+    state, pool, and every per-lane output."""
+    got, ref = _slab_and_per_array(program)
+    assert len(got) == len(ref)
+    for g, r in zip(got, ref):
+        _bits_equal(g, r)
+
+
+@pytest.mark.parametrize("program", ["fused_step", "fused_admit",
+                                     "drop_slots"])
+def test_lowered_entry_point_takes_one_lane_slab(program):
+    """Each lowered program takes the state (and pool) plus ONE array of
+    lanes; the step gives the state and pool back plus ONE array."""
+    from repro.core import tac_jax
+    W, V, B = 16, 2, 8
+    state = tac_jax.init(1, W, 1)
+    pages = jnp.zeros((W + 1, 1, V + 1), jnp.float32)
+    i32, f32 = np.zeros(B, np.int32), np.zeros(B, np.float32)
+    flags, rows = np.zeros(B, bool), np.zeros((B, V), np.float32)
+    n_state = len(jax.tree_util.tree_leaves(state))
+    if program == "fused_step":
+        low = tac_jax.fused_step.lower(state, pages, i32, f32, rows, flags,
+                                       flags, kind="sum")
+        width, fixed = V + 4, n_state + 1
+        out = jax.tree_util.tree_leaves(low.out_info)
+        assert len(out) == n_state + 2
+        assert (out[-1].shape, out[-1].dtype) == ((B + 1, V + 3), jnp.int32)
+    elif program == "fused_admit":
+        low = tac_jax.fused_admit.lower(state, pages, i32, i32, f32, rows,
+                                        flags, flags)
+        width, fixed = V + 5, n_state + 1
+    else:
+        low = tac_jax.drop_slots.lower(state, i32, flags)
+        width, fixed = 2, n_state
+    args = jax.tree_util.tree_leaves(low.args_info)
+    assert len(args) == fixed + 1
+    assert (args[-1].shape, args[-1].dtype) == ((B, width), jnp.int32)
+    assert f"module @jit_{program}" in low.as_text()

@@ -98,6 +98,8 @@ def test_catalog_template_matching():
     assert matches_catalog("trace.stage.park_wait")
     assert matches_catalog("engine.stateful.fused.calls.gather_rows")
     assert matches_catalog("engine.stateful.fused.victim_reads")
+    assert matches_catalog("engine.stateful.fused.transfers.to_device")
+    assert matches_catalog("engine.join.fused.transfers.to_host")
     assert matches_catalog("engine.stateful.fused.shadow_reads")
     assert matches_catalog("engine.span.fused.readback.self_s")
     assert matches_catalog("engine.span.source.tick.count")

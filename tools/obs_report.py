@@ -101,8 +101,15 @@ def print_fused(fb: dict) -> None:
     print(f"  {'device misses':<16s} {fb.get('device_misses', 0):>8d}")
     print(f"  {'conflicts':<16s} {fb.get('device_conflicts', 0):>8d}   "
           f"(misses beyond free device slots at adjudication)")
-    for prog, n in fb.get("calls", {}).items():
+    calls = fb.get("calls", {})
+    for prog, n in calls.items():
         print(f"  {'calls ' + prog:<16s} {n:>8d}")
+    tr = fb.get("transfers")
+    if tr and calls.get("fused_step"):
+        print(f"  {'transfers/call':<16s} "
+              f"{tr['to_device'] / max(1, sum(calls.values())):>8.2f} in  "
+              f"{tr['to_host'] / calls['fused_step']:.2f} out   "
+              f"(host arrays per device call; read back per step)")
     print(f"  {'victim reads':<16s} {fb.get('victim_reads', 0):>8d}   "
           f"(dirty victims with no queued row)")
     print(f"  {'shadow reads':<16s} {fb.get('shadow_reads', 0):>8d}   "
