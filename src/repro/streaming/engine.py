@@ -1078,18 +1078,21 @@ class StatefulOp(Operator):
         return 0.4e-6       # hash probe + buffer insert, no deserialization
 
     def _on_data(self, sub: int, tup: Tuple_) -> float:
-        cache = self.caches[sub]
         tr = tup.trace
         if tr is not None:
             tr.mark_state(self.name, self.sim.t)
-        state = cache.lookup(tup.key, tup.ts)
+        return self._access(sub, tup, self.caches[sub].lookup(tup.key,
+                                                              tup.ts))
+
+    def _access(self, sub: int, tup: Tuple_, state: Any) -> float:
+        """Everything a data access does after its cache lookup returned
+        ``state``: apply on a hit, else serve from the write-back
+        memtable, fetch synchronously, or park the tuple on a fetch.
+        Shared by the interpreted and the fused path (§14)."""
+        cache = self.caches[sub]
+        tr = tup.trace
         if state is not None:
-            if tr is not None and tr.hit is None:
-                tr.hit = True
-            if self.recorder.pending_suppressed:
-                # grade a pending hint suppression for this key: the key
-                # was resident, so the suppression was correct (§13)
-                self.recorder.on_access(tup.key, hit=True)
+            self._grade(tr, tup.key, True)
             if self.mode == "prefetch":
                 self.managers[sub].prefetch_hits += 1
                 if self.shards is not None:
@@ -1100,19 +1103,10 @@ class StatefulOp(Operator):
         if wb is not None:
             # key's latest state rides an in-flight write-back: a backend
             # fetch would read STALE data — serve from the memtable
-            if tr is not None and tr.hit is None:
-                tr.hit = True
-            if self.recorder.pending_suppressed:
-                self.recorder.on_access(tup.key, hit=True)
+            self._grade(tr, tup.key, True)
             cache.insert(tup.key, wb.state, tup.ts, size=self.state_size)
             return self._apply(sub, tup, wb.state)
-        # miss
-        if tr is not None and tr.hit is None:
-            tr.hit = False
-        if self.recorder.pending_suppressed:
-            # the suppressed hint would have prefetched this key:
-            # incorrect suppression (it costs a demand fetch)
-            self.recorder.on_access(tup.key, hit=False)
+        self._grade(tr, tup.key, False)
         if self.mode == "prefetch" and not self.managers[sub].enabled:
             la = self.managers[sub].on_cache_misses(self.sim.t)
             if la is not None:
@@ -1138,6 +1132,17 @@ class StatefulOp(Operator):
                              front=True)
         # completed-fetch scanning cost grows with outstanding async ops
         return IO_ISSUE * (1.0 + len(self.in_flight[sub]) / 32.0)
+
+    def _grade(self, tr, key, hit: bool) -> None:
+        """Record whether an access to ``key`` found its state: on a
+        sampled trace (its first access decides) and as the grade of a
+        pending hint suppression for the key (§13): resident, the
+        suppression was correct; a miss costs the demand fetch the
+        suppressed hint would have saved."""
+        if tr is not None and tr.hit is None:
+            tr.hit = hit
+        if self.recorder.pending_suppressed:
+            self.recorder.on_access(key, hit=hit)
 
     # ------------------------------------------------------------------- IO
     def _io_enqueue(self, sub: int, req: _IOReq, front: bool = False) -> None:
@@ -1272,7 +1277,12 @@ class StatefulOp(Operator):
             self.caches[sub].write(tup.key, new_state, tup.ts,
                                    size=self.state_size)
             self._io_kick(sub)             # opportunistic write-back
-        tr = tup.trace
+        self._emit_traced(sub, tup.trace, outputs)
+        return self.service_time
+
+    def _emit_traced(self, sub: int, tr, outputs: List[Any]) -> None:
+        """Emit ``outputs``, each carrying trace ``tr`` unless it has its
+        own, or finish ``tr`` as absorbed when there are none."""
         if tr is not None:
             tr.mark_apply(self.sim.t)
         for o in outputs:
@@ -1282,7 +1292,6 @@ class StatefulOp(Operator):
             self.emit(sub, o)
         if not outputs:
             self._trace_absorbed(tr)
-        return self.service_time
 
     def _trace_absorbed(self, tr) -> None:
         """Finalize a sampled tuple CONSUMED into operator state with no
@@ -1360,20 +1369,13 @@ class StatefulOp(Operator):
         return (tup.key,), False
 
     def _fused_expand(self, sub: int, tup: Tuple_,
-                      keys=None) -> List[Lane]:
+                      keys) -> List[Lane]:
         """Turn one dequeued tuple into device lanes (``keys`` is the
         prospect's precomputed key tuple, so expansion never redoes the
-        window assignment).  Windowed subclasses expand to panes and
-        take the late checks here — mirroring their ``_on_data``
-        expansion."""
+        window assignment).  Windowed subclasses expand to panes through
+        the pane assignment their ``_on_data`` uses."""
         return [Lane(tup.key, tup.ts, self.fused_spec.weight_raw(tup),
                      False, False, tup)]
-
-    def _fused_fire(self, sub: int, lane: Lane, state: Any) -> None:
-        raise RuntimeError("fire lane on a non-windowed operator")
-
-    def _fused_late(self, sub: int, lane: Lane, state: Any) -> None:
-        raise RuntimeError("late-update lane on a non-windowed operator")
 
     def _fused_lane_tuple(self, lane: Lane) -> Tuple_:
         """The tuple a lane parks/applies as: the source tuple itself,
@@ -1429,12 +1431,13 @@ class StatefulOp(Operator):
     def _fused_step(self, sub: int, lanes: List[Lane]) -> float:
         """One device batch + host post-step.  Device-HIT lanes finished
         on device (state read/updated/written back in the jitted
-        program); every other lane is re-adjudicated IN LANE ORDER
-        through the interpreted cold paths (eviction-buffer restores,
+        program); every other lane runs, IN LANE ORDER, the interpreted
+        access after its lookup (``_access``: eviction-buffer restores,
         memtable shield, sync refetch or parking) so counters, emits,
-        and state stay sequential-equivalent (§14)."""
+        and state stay sequential-equivalent (§14; in ``sync`` mode a
+        lane's fetch can evict a key a later lane already hit, so the
+        eviction counts can differ)."""
         plane = self.caches[sub]
-        mgr = self.managers[sub]
         spec = self.fused_spec
         n = len(lanes)
         res = plane.batch_step(lanes)
@@ -1443,12 +1446,12 @@ class StatefulOp(Operator):
             spans.enter(self._span_adjudicate)
         svc = FUSED_LAUNCH + FUSED_LANE * n
         if self.mode == "prefetch":
-            mgr.prefetch_hits += int(res.hit.sum())
+            self.managers[sub].prefetch_hits += int(res.hit.sum())
         # vectorized fast path: a PLAIN hit lane (update absorbed on
         # device — not a fire, not a late update, no per-lane emits)
         # needs no host work at all unless a trace or the hint-quality
         # recorder is watching.  Only the exceptional lanes get the
-        # per-lane branch cascade below.
+        # per-lane branch below.
         lane_idx = range(n)
         if spec.emit_of is None and not self.recorder.pending_suppressed:
             late = np.fromiter((ln.late_update for ln in lanes), bool, n)
@@ -1462,85 +1465,27 @@ class StatefulOp(Operator):
             tr = tup.trace
             if tr is not None:
                 tr.mark_state(self.name, self.sim.t)
-            if res.hit[i]:
-                if tr is not None and tr.hit is None:
-                    tr.hit = True
-                if self.recorder.pending_suppressed:
-                    self.recorder.on_access(ln.key, hit=True)
-                if ln.fire:
-                    self._fused_fire(sub, ln, plane.decode_lane(res, i))
-                elif ln.late_update:
-                    self._fused_late(sub, ln, plane.decode_lane(res, i))
-                else:
-                    # per-lane emits from the composed post-lane value
-                    # (read enrichment, or sum/max specs with emit_of);
-                    # no emit_of = the update is absorbed on device
-                    outs = spec.emit_of(tup, plane.decode_lane(res, i)) \
-                        if spec.emit_of is not None else []
-                    if tr is not None:
-                        tr.mark_apply(self.sim.t)
-                    for o in outs:
-                        self.outputs += 1
-                        if tr is not None and \
-                                getattr(o, "trace", None) is None:
-                            o.trace = tr
-                        self.emit(sub, o)
-                    if not outs:
-                        self._trace_absorbed(tr)
+            if not res.hit[i]:
+                # the lookup is the one _on_data makes: ln.key and ln.ts
+                # are the lane tuple's key and ts
+                svc += self._access(sub, self._fused_lane_tuple(ln),
+                                    plane.lookup(ln.key, ln.ts))
                 continue
-            # ---- non-hit lane: interpreted adjudication, lane order
-            ptup = self._fused_lane_tuple(ln)
-            state = plane.lookup(ln.key, ln.ts)
-            if state is not None:
-                # eviction-buffer restore, or a key admitted by an
-                # earlier lane's cold path in this very drain
-                if tr is not None and tr.hit is None:
-                    tr.hit = True
-                if self.recorder.pending_suppressed:
-                    self.recorder.on_access(ln.key, hit=True)
-                if self.mode == "prefetch":
-                    mgr.prefetch_hits += 1
-                svc += self._apply(sub, ptup, state)
-                continue
-            wb = self.wb_pending[sub].get(ln.key)
-            if wb is not None:
-                if tr is not None and tr.hit is None:
-                    tr.hit = True
-                if self.recorder.pending_suppressed:
-                    self.recorder.on_access(ln.key, hit=True)
-                plane.insert(ln.key, wb.state, ln.ts,
-                             size=self.state_size)
-                svc += self._apply(sub, ptup, wb.state)
-                continue
-            if tr is not None and tr.hit is None:
-                tr.hit = False
-            if self.recorder.pending_suppressed:
-                self.recorder.on_access(ln.key, hit=False)
-            if self.mode == "prefetch" and not mgr.enabled:
-                la = mgr.on_cache_misses(self.sim.t)
-                if la is not None:
-                    self.engine.set_lookahead(self.name, la)
-            if self.mode == "sync":
-                state, lat = self.backends[sub].fetch(ln.key,
-                                                      self.state_size)
-                plane.insert(ln.key, state, ln.ts, size=self.state_size)
-                mgr.record_access_latency(lat)
-                self.blocked_time[sub] += lat
-                self.pf_demand.inc()
-                if tr is not None:
-                    tr.fetch_s += lat
-                svc += lat + self._apply(sub, ptup, state)
-                continue
-            if tr is not None:
-                tr.mark_park(self.sim.t)
-            if ln.key not in self._park_t[sub]:
-                self._park_t[sub][ln.key] = self.sim.t
-            self.waiting[sub][ln.key].append(ptup)
-            if ln.key not in self.in_flight[sub]:
-                self.pf_demand.inc()
-                self._io_enqueue(sub, _IOReq("read", ln.key, ln.ts),
-                                 front=True)
-            svc += IO_ISSUE * (1.0 + len(self.in_flight[sub]) / 32.0)
+            self._grade(tr, ln.key, True)
+            # fire and late-update lanes come only from windowed
+            # operators' expansions
+            if ln.fire:
+                self._emit_fire(sub, tup, plane.decode_lane(res, i))
+            elif ln.late_update:
+                self._emit_late(sub, self._fused_lane_tuple(ln),
+                                plane.decode_lane(res, i))
+            else:
+                # per-lane emits from the composed post-lane value
+                # (read enrichment, or sum/max specs with emit_of);
+                # no emit_of = the update is absorbed on device
+                self._emit_traced(sub, tr, [] if spec.emit_of is None
+                                  else spec.emit_of(
+                                      tup, plane.decode_lane(res, i)))
         self._io_kick(sub)          # opportunistic write-back, per batch
         if spans.enabled:
             spans.exit()

@@ -194,10 +194,9 @@ class FusedPlane:
         # (slot-keyed, so a re-write before the flush supersedes in
         # place) and _flush_admits lands the whole backlog in chunked
         # fused_admit calls right before the next device op needs it.
-        # _pending_state mirrors the encoded rows so reads of a queued
-        # slot are served host-side without touching the device.
+        # The value shadow already holds each queued row, so reads of a
+        # queued slot never touch the device either.
         self._pending_admits: Dict[int, list] = {}
-        self._pending_state: Dict[int, tuple] = {}
         # lazy victim heaps, the same structure the interpreted TAC
         # uses: (ts, gen, slot) min-order and (-ts, gen, slot) for the
         # deadline-aware farthest-first rule.  gen is a unique version
@@ -231,7 +230,8 @@ class FusedPlane:
         self.device_conflicts = 0
         # one per device call, by program; host arrays handed to and
         # read back from device calls (one slab each way, §14); dirty
-        # victims read back; rows served from the value shadow
+        # victims with no queued row; rows served from the value shadow,
+        # queued ones included
         self.calls: Dict[str, int] = dict.fromkeys(self.PROGRAMS, 0)
         self.transfers: Dict[str, int] = dict.fromkeys(self.TRANSFERS, 0)
         self.victim_reads = 0
@@ -285,7 +285,6 @@ class FusedPlane:
             spans.enter("stream.fused.admit")
         recs = list(self._pending_admits.items())
         self._pending_admits.clear()
-        self._pending_state.clear()
         i = 0
         while i < len(recs):
             chunk = recs[i:i + 64]
@@ -361,10 +360,9 @@ class FusedPlane:
 
     def _account_eviction(self, slot: int, reason: str) -> None:
         """Runs BEFORE the new occupant is queued at ``slot``.  A dirty
-        victim's value comes from its own queued admission if it never
-        reached the device, else from the value shadow — no device call
-        either way; clean victims (the common prefetch-churn case) touch
-        nothing."""
+        victim's value comes from the value shadow, whether or not its
+        row reached the device — no device call; clean victims (the
+        common prefetch-churn case) touch nothing."""
         key = self._key_by_slot[slot]
         self.evictions += 1
         adm = "prefetched" if self._spf[slot] else "demand"
@@ -378,12 +376,12 @@ class FusedPlane:
             if self.recorder is not None:
                 self.recorder.on_wasted()
         if self._sdirty[slot]:
-            pend = self._pending_state.get(slot)
-            if pend is not None:
-                state = self.spec.dec(pend[0], pend[1])
-            else:
+            # a victim whose row is still queued never reached the device
+            queued = slot in self._pending_admits
+            if not queued:
                 self.victim_reads += 1
-                state = self._shadow_read(slot, "stream.fused.victim_read")
+            state = self._shadow_read(
+                slot, None if queued else "stream.fused.victim_read")
             e = Entry(key, state, float(self._sts[slot]), True,
                       self.entry_size)
             e.prefetched = bool(self._spf[slot])
@@ -393,7 +391,6 @@ class FusedPlane:
         # a queued admission evicted before it ever landed is cancelled;
         # the new occupant's queued row overwrites the slot at flush
         self._pending_admits.pop(slot, None)
-        self._pending_state.pop(slot, None)
         del self._slot_by_key[key]
         self._key_by_slot[slot] = None
         self.used -= self.entry_size
@@ -417,7 +414,6 @@ class FusedPlane:
         vec, present = self.spec.enc(state)
         self._pending_admits[slot] = [self._intern(key), float(ts), vec,
                                       present, dirty]
-        self._pending_state[slot] = (vec, present)
         self._sval[slot] = vec
         self._spres[slot] = present
         self._sid[slot] = self._ids[key]
@@ -433,23 +429,18 @@ class FusedPlane:
         if prefetched and self.recorder is not None:
             self._sstage_t[slot] = self.recorder.now()
 
-    def _read_slot(self, slot: int):
-        pend = self._pending_state.get(slot)
-        if pend is not None:
-            return self.spec.dec(pend[0], pend[1])
-        return self._shadow_read(slot, "stream.fused.slot_read")
-
-    def _shadow_read(self, slot: int, span: str):
+    def _shadow_read(self, slot: int, span: Optional[str]):
         """One slot's row from the value shadow, decoded: what the pool
-        holds there, with no device call."""
-        spans = self.spans
-        if spans.enabled:
-            spans.enter(span)
+        holds there, or will once the queued admissions land, with no
+        device call.  Timed under ``span`` unless it is None."""
+        on = span is not None and self.spans.enabled
+        if on:
+            self.spans.enter(span)
         self.shadow_reads += 1
         state = self.spec.dec(self._sval[slot].copy(),
                               bool(self._spres[slot]))
-        if spans.enabled:
-            spans.exit()
+        if on:
+            self.spans.exit()
         return state
 
     def _restore(self, staged: Entry, ts: float) -> None:
@@ -478,7 +469,7 @@ class FusedPlane:
         if self._spf_unused[slot] and self.recorder is not None:
             self.recorder.on_used(float(self._sstage_t[slot]))
         self._spf_unused[slot] = False
-        return self._read_slot(slot)
+        return self._shadow_read(slot, "stream.fused.slot_read")
 
     def contains(self, key) -> bool:
         return key in self._slot_by_key or key in self.evict_buffer
@@ -523,7 +514,6 @@ class FusedPlane:
         slot = self._slot_by_key.pop(key, None)
         if slot is not None:
             self._pending_admits.pop(slot, None)
-            self._pending_state.pop(slot, None)
             self._sid[slot] = -1
             self._sts[slot] = -np.inf
             self._sdirty[slot] = False

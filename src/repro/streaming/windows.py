@@ -178,11 +178,36 @@ class WindowedStatefulOp(StatefulOp):
         if isinstance(tup.key, WindowKey):
             # already a pane access: a migration replay or parked resume
             return super()._on_data(sub, tup)
+        # every pane is assigned before the first is accessed, which is
+        # what assigning and accessing pane by pane would do: a data
+        # access changes neither the window registry nor the watermark
+        # (panes purge only at a FIRE or a watermark)
+        panes = self._assign_panes(sub, tup, self._pane_keys(tup))
+        if not panes:
+            return 5e-7
+        svc = 0.0
+        for wk, late in panes:
+            svc += super()._on_data(sub, Tuple_(
+                tup.ts, wk, tup.payload, tup.size, tup.ingest_t,
+                trace=tup.trace, late=late))
+        return svc
+
+    def _pane_keys(self, tup: Tuple_):
+        return tuple(WindowKey(tup.key, wid)
+                     for wid in self.assigner.assign(tup.ts))
+
+    def _assign_panes(self, sub: int, tup: Tuple_, wks):
+        """The live panes of base tuple ``tup``, whose pane keys are
+        ``wks``, as ``(pane key, late)`` pairs.  Drops and counts a pane
+        beyond the lateness horizon, or one whose window fired under the
+        drop policy; registers the rest in the window registry, a key
+        joining a fired window (update policy, ``late``) as fired.  A
+        tuple with no live pane finishes its trace as absorbed."""
         wm = self.wm[sub]
-        svc, n = 0.0, 0
-        for wid in self.assigner.assign(tup.ts):
-            end = self.assigner.end(wid)
-            if end + self.allowed_lateness < wm:
+        panes = []
+        for wk in wks:
+            wid = wk.wid
+            if self.assigner.end(wid) + self.allowed_lateness < wm:
                 self.late_dropped += 1          # beyond the lateness horizon
                 continue
             meta = self.windows[sub].get(wid)
@@ -199,140 +224,54 @@ class WindowedStatefulOp(StatefulOp):
                 # late key joining a fired window (update policy): it
                 # emits per-tuple updates, never a FIRE of its own
                 meta["fired_keys"].add(tup.key)
-            n += 1
-            svc += super()._on_data(sub, Tuple_(
-                tup.ts, WindowKey(tup.key, wid), tup.payload, tup.size,
-                tup.ingest_t, trace=tup.trace, late=meta["fired"]))
-        if not n:
+            panes.append((wk, meta["fired"]))
+        if not panes:
             self._trace_absorbed(tup.trace)  # dropped before any pane
-        return svc if n else 5e-7
+        return panes
 
-    def _apply(self, sub: int, tup: Tuple_, state: Any) -> float:
-        wk: WindowKey = tup.key
-        if tup.payload is FIRE:
-            end = self.assigner.end(wk.wid)
-            payload = self.emit_fn(wk.base, wk.wid, end, state)
-            self.fires += 1
-            if self.engine.record_events:
-                self.engine.log_event("fire", op=self.name, wid=wk.wid)
-            if payload is not None:
-                self.outputs += 1
-                self.emit(sub, Tuple_(end, wk.base, payload, self.out_size,
-                                      tup.ingest_t, trace=tup.trace))
-            if self.allowed_lateness == 0:
-                self._purge_pane(sub, wk)
-            return self.service_time
-        meta = self.windows[sub].get(wk.wid)
-        # lateness is the tuple's place in the input relative to the
-        # watermark, fixed when it was assigned to the pane: one that
-        # parked on a fetch across the fire is still on time (the FIRE
-        # parks behind it, so the fired result counts it).  Re-delivered
-        # pane accesses carry no mark and are judged now
+    def _pane_late(self, sub: int, tup: Tuple_) -> Optional[bool]:
+        """Whether pane access ``tup`` is a late update, or None when the
+        drop policy discards it (counted, trace finished).  Lateness is
+        the tuple's place in the input relative to the watermark, fixed
+        when it was assigned to the pane: one that parked on a fetch
+        across the fire is still on time (the FIRE parks behind it, so
+        the fired result counts it).  Re-delivered pane accesses carry
+        no mark and are judged now."""
         late = tup.late
         if late is None:
+            meta = self.windows[sub].get(tup.key.wid)
             late = meta is not None and meta["fired"]
         if late and self.late_policy != "update":
-            # drop policy, yet the tuple reached _apply after the fire:
+            # drop policy, yet the tuple reached its pane after the fire:
             # its contribution can no longer reach the fired result (and
             # writing would resurrect a purged pane)
             self.late_dropped += 1
             self._trace_absorbed(tup.trace)
+            return None
+        return late
+
+    def _apply(self, sub: int, tup: Tuple_, state: Any) -> float:
+        wk: WindowKey = tup.key
+        if tup.payload is FIRE:
+            self._emit_fire(sub, tup, state)
+            return self.service_time
+        late = self._pane_late(sub, tup)
+        if late is None:
             return self.service_time
         acc = self.agg_fn(tup, state)
-        emitted = False
         if late:
-            # late-side update: re-emit the refreshed result immediately
-            self.late_updates += 1
-            payload = self.emit_fn(wk.base, wk.wid,
-                                   self.assigner.end(wk.wid), acc)
-            if payload is not None:
-                self.outputs += 1
-                emitted = True
-                self.emit(sub, Tuple_(tup.ts, wk.base, payload,
-                                      self.out_size, tup.ingest_t,
-                                      trace=tup.trace))
+            self._emit_late(sub, tup, acc)
         if acc is not state:
             self.caches[sub].write(wk, acc, tup.ts, size=self.state_size)
             self._io_kick(sub)
-        if not emitted:
+        if not late:
             self._trace_absorbed(tup.trace)  # folded into the pane
         return self.service_time
 
-    # ------------------------------------------------------ fused data path
-    def _fused_prospect(self, sub: int, tup: Tuple_):
-        if isinstance(tup.key, WindowKey):
-            return (tup.key,), tup.payload is FIRE
-        return (tuple(WindowKey(tup.key, wid)
-                      for wid in self.assigner.assign(tup.ts)), False)
-
-    def _fused_expand(self, sub: int, tup: Tuple_, keys=None):
-        """Pane expansion for a fused batch, mirroring ``_on_data``: the
-        lateness-horizon and fired-window checks run here (device lanes
-        cannot re-check mid-batch; no watermark can interleave, so the
-        decision is the same one ``_apply`` would take).  FIRE lanes ride
-        as read-only lanes; tuples joining a fired window under the
-        update policy become late-update lanes (§14).  ``keys`` reuses
-        the prospect's WindowKeys so assignment runs once per tuple."""
-        spec = self.fused_spec
-        zeros = (0.0,) * spec.width
-        if isinstance(tup.key, WindowKey):
-            wk = tup.key
-            if tup.payload is FIRE:
-                return [Lane(wk, tup.ts, zeros, True, False, tup)]
-            # replayed / re-delivered pane access (migration replay is
-            # unreachable — fused excludes shards — but recovery
-            # re-delivery lands here): the same lateness rule as _apply
-            late = tup.late
-            if late is None:
-                meta = self.windows[sub].get(wk.wid)
-                late = meta is not None and meta["fired"]
-            if late:
-                if self.late_policy != "update":
-                    self.late_dropped += 1
-                    self._trace_absorbed(tup.trace)
-                    return []
-                return [Lane(wk, tup.ts, spec.weight_raw(tup), False,
-                             True, tup)]
-            return [Lane(wk, tup.ts, spec.weight_raw(tup), False, False,
-                         tup)]
-        wm = self.wm[sub]
-        out = []
-        wks = keys if keys is not None \
-            else tuple(WindowKey(tup.key, wid)
-                       for wid in self.assigner.assign(tup.ts))
-        w_raw = None
-        for wk in wks:
-            wid = wk.wid
-            end = self.assigner.end(wid)
-            if end + self.allowed_lateness < wm:
-                self.late_dropped += 1          # beyond the horizon
-                continue
-            meta = self.windows[sub].get(wid)
-            if meta is not None and meta["fired"] \
-                    and self.late_policy == "drop":
-                self.late_dropped += 1          # fired, drop-policy
-                continue
-            if meta is None:
-                meta = {"keys": set(), "fired": False,
-                        "fired_keys": set()}
-                self.windows[sub][wid] = meta
-            meta["keys"].add(tup.key)
-            late = meta["fired"]
-            if late:
-                meta["fired_keys"].add(tup.key)
-            if w_raw is None:
-                w_raw = spec.weight_raw(tup)
-            out.append(Lane(wk, tup.ts, w_raw, False, late, tup))
-        if not out:
-            self._trace_absorbed(tup.trace)     # dropped before any pane
-        return out
-
-    def _fused_fire(self, sub: int, lane: Lane, state: Any) -> None:
-        """Device-hit FIRE lane: the pane value came back in the batch
-        read — emit exactly like ``_apply``'s FIRE branch.  (A fire lane
-        whose pane was evicted device-misses and parks/refetches through
-        the interpreted path instead.)"""
-        wk: WindowKey = lane.key
+    def _emit_fire(self, sub: int, tup: Tuple_, state: Any) -> None:
+        """A FIRE for pane ``tup.key`` read ``state``: emit the window's
+        result, and purge the pane when no lateness retains it."""
+        wk: WindowKey = tup.key
         end = self.assigner.end(wk.wid)
         payload = self.emit_fn(wk.base, wk.wid, end, state)
         self.fires += 1
@@ -341,24 +280,58 @@ class WindowedStatefulOp(StatefulOp):
         if payload is not None:
             self.outputs += 1
             self.emit(sub, Tuple_(end, wk.base, payload, self.out_size,
-                                  lane.tup.ingest_t, trace=lane.tup.trace))
+                                  tup.ingest_t, trace=tup.trace))
         if self.allowed_lateness == 0:
             self._purge_pane(sub, wk)
 
-    def _fused_late(self, sub: int, lane: Lane, acc: Any) -> None:
-        """Device-hit late-update lane: the device already composed and
-        wrote the refreshed accumulator; re-emit it (§10 update policy)."""
-        wk: WindowKey = lane.key
-        tup = lane.tup
+    def _emit_late(self, sub: int, tup: Tuple_, acc: Any) -> None:
+        """Late-side update (update policy): re-emit pane ``tup.key``'s
+        refreshed result ``acc`` now, or finish the trace as absorbed
+        when ``emit_fn`` gives no result."""
+        wk: WindowKey = tup.key
         self.late_updates += 1
         payload = self.emit_fn(wk.base, wk.wid, self.assigner.end(wk.wid),
                                acc)
-        if payload is not None:
-            self.outputs += 1
-            self.emit(sub, Tuple_(tup.ts, wk.base, payload, self.out_size,
-                                  tup.ingest_t, trace=tup.trace))
-        else:
+        if payload is None:
             self._trace_absorbed(tup.trace)
+            return
+        self.outputs += 1
+        self.emit(sub, Tuple_(tup.ts, wk.base, payload, self.out_size,
+                              tup.ingest_t, trace=tup.trace))
+
+    # ------------------------------------------------------ fused data path
+    def _fused_prospect(self, sub: int, tup: Tuple_):
+        if isinstance(tup.key, WindowKey):
+            return (tup.key,), tup.payload is FIRE
+        return self._pane_keys(tup), False
+
+    def _fused_expand(self, sub: int, tup: Tuple_, keys):
+        """Pane expansion for a fused batch, through ``_on_data``'s pane
+        assignment and ``_apply``'s lateness rule: device lanes cannot
+        re-check mid-batch, and no watermark can interleave, so the
+        decision is the one the interpreted path takes.  FIRE lanes ride
+        as read-only lanes; tuples joining a fired window under the
+        update policy become late-update lanes (§14).  ``keys`` reuses
+        the prospect's WindowKeys so assignment runs once per tuple."""
+        spec = self.fused_spec
+        if isinstance(tup.key, WindowKey):
+            if tup.payload is FIRE:
+                return [Lane(tup.key, tup.ts, (0.0,) * spec.width, True,
+                             False, tup)]
+            # a re-delivered pane access (migration replay is
+            # unreachable — fused excludes shards — but recovery
+            # re-delivery lands here)
+            late = self._pane_late(sub, tup)
+            if late is None:
+                return []
+            return [Lane(tup.key, tup.ts, spec.weight_raw(tup), False, late,
+                         tup)]
+        panes = self._assign_panes(sub, tup, keys)
+        if not panes:
+            return []
+        w_raw = spec.weight_raw(tup)
+        return [Lane(wk, tup.ts, w_raw, False, late, tup)
+                for wk, late in panes]
 
     # ---------------------------------------------------------------- firing
     def on_watermark(self, sub: int, wm: float) -> None:

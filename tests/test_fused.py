@@ -10,7 +10,9 @@ the event timeline and eviction-order counters may diverge; state and
 emitted tuples match regardless.  Quiescing pins the interleaving, and
 then EVERYTHING must match bit-exactly: final backend state, emitted
 tuples, and the §12 counter totals (hits/misses/evictions by reason,
-writebacks, late drops/updates, parked-tuple demand fetches).
+writebacks, late drops/updates, parked-tuple demand fetches) — in
+``sync`` mode the counters that hang on eviction choice excepted
+(``PER_TUPLE_COUNTERS`` below says why).
 """
 import os
 import sys
@@ -53,12 +55,84 @@ class Collect(SinkOp):
         return super().process(sub, tup)
 
 
+def _record_emits(op):
+    """Every tuple ``op`` emits, as (ts, key, payload, emission sim time)."""
+    out, emit = [], op.emit
+
+    def recording(sub, msg):
+        out.append((msg.ts, msg.key, msg.payload, op.sim.t))
+        return emit(sub, msg)
+    op.emit = recording
+    return out
+
+
+def _source_tuple(eng, ts, key, payload=None):
+    """A data tuple as a source emits it: sampled for a trace when the
+    engine's tracing is on."""
+    tup = Tuple_(ts, key, payload, 64, 0.0)
+    if eng.tracer.sample_every:
+        tup.trace = eng.tracer.maybe_start(eng.sim.t)
+    return tup
+
+
+TRACE_COUNTERS = ("sampled", "finished", "probe_hits", "probe_misses")
+# the counters no eviction choice moves.  In sync mode a lane's fetch
+# can evict a key that a later lane of the same device batch already
+# hit, where the interpreted plane evicts that key and then restores it
+# from the eviction buffer: hit, miss and eviction counts then differ
+# between the planes, while state and emits agree
+PER_TUPLE_COUNTERS = ("processed", "outputs", "sampled", "finished",
+                      "fires", "late_dropped", "late_updates", "purged")
+
+
 def _counters(op):
     cache = op.caches[0]
-    return dict(hits=cache.hits, misses=cache.misses,
-                evictions=cache.evictions, writebacks=cache.writebacks,
-                by_reason=cache.eviction_block(), processed=op.processed,
-                outputs=op.outputs, pf_demand=op.pf_demand.value)
+    out = dict(hits=cache.hits, misses=cache.misses,
+               evictions=cache.evictions, writebacks=cache.writebacks,
+               by_reason=cache.eviction_block(), processed=op.processed,
+               outputs=op.outputs, pf_demand=op.pf_demand.value)
+    tracer = op.engine.tracer
+    if tracer.active:
+        s = tracer.summary()
+        out.update({k: s[k] for k in TRACE_COUNTERS})
+    return out
+
+
+def _assert_counters_match(ci, cf, mode):
+    if mode == "sync":
+        # each plane alone: every miss of these runs is one synchronous
+        # fetch (none is served from the write-back memtable)
+        for c in (ci, cf):
+            assert c["pf_demand"] == c["misses"], c
+        def per_tuple(c):
+            out = {k: c[k] for k in PER_TUPLE_COUNTERS if k in c}
+            out["accesses"] = c["hits"] + c["misses"]
+            return out
+        ci, cf = per_tuple(ci), per_tuple(cf)
+    assert ci == cf, f"counter mismatch\ninterp={ci}\nfused={cf}"
+
+
+def _assert_emits_match(ei, ef):
+    """Both planes emit the same tuples.  Emission sim times are not
+    compared across planes: a fused batch emits at its drain's time,
+    the interpreted plane spaces tuples by their service time."""
+    assert sorted(e[:3] for e in ei) == sorted(e[:3] for e in ef), \
+        f"emit mismatch\ninterp={ei}\nfused={ef}"
+
+
+def _assert_trace_moves_nothing(traced, untraced):
+    """A plane's traced run emits the same tuples at the same sim times,
+    with the same counters, as its untraced run: a trace changes no
+    decision.  On the fused plane the untraced run takes the vectorised
+    plain-hit path and the traced one the per-lane path."""
+    (_, c, e), (_, c0, e0) = traced, untraced
+    assert e == e0
+    assert {k: v for k, v in c.items() if k not in TRACE_COUNTERS} == c0
+
+
+PLANES_MODES = [pytest.param(mode, traced, id=f"{mode}-{tr}")
+                for mode in ("sync", "async")
+                for traced, tr in ((False, "untraced"), (True, "traced"))]
 
 
 def _final_state(op, state_size):
@@ -83,24 +157,40 @@ def assert_shadow_is_pool(plane):
 
 
 # ------------------------------------------------------------ base operator
-def run_base(keys, fused, cache_entries=8, batch=8):
+def run_base(keys, fused, cache_entries=8, batch=8, mode="async",
+             traced=False, emits=False):
     """Count-per-key through a bare StatefulOp under the quiesced
-    protocol; returns (state, counters)."""
+    protocol; returns (state, counters, emits).  ``emits`` makes the
+    operator emit each tuple's new count (``emit_of`` on the fused
+    plane), ``traced`` samples every tuple for a trace."""
     eng = Engine()
-    kw = dict(policy="tac", mode="async", cache_capacity=cache_entries * 64,
+    if traced:
+        eng.enable_tracing(sample_every=1)
+    kw = dict(policy="tac", mode=mode, cache_capacity=cache_entries * 64,
               state_size=64, io_workers=2)
+
+    def out_of(tup, n):
+        return [Tuple_(tup.ts, tup.key, n, 64, tup.ingest_t)] if emits \
+            else []
     if fused:
-        kw["fused"] = count_spec()
+        spec = count_spec()
+        if emits:
+            spec.emit_of = out_of
+        kw["fused"] = spec
         kw["fused_batch"] = batch
 
     def apply_count(tup, state):
-        return ((state or 0) + 1, [])
+        n = (state or 0) + 1
+        return n, out_of(tup, n)
 
     op = StatefulOp(eng, "agg", 1, apply_count, LOCAL_NVME, **kw)
     eng.add(op)
+    sink = eng.add(Collect(eng, "sink", 1))
+    eng.connect(op, sink)
+    emitted = _record_emits(op)
     t = 0.0
     for i in range(0, len(keys), 6):
-        op.deliver_batch(0, [Tuple_(float(j), keys[j], None, 64, 0.0)
+        op.deliver_batch(0, [_source_tuple(eng, float(j), keys[j])
                              for j in range(i, min(i + 6, len(keys)))])
         t += 0.05
         eng.sim.run_until(t)
@@ -109,23 +199,36 @@ def run_base(keys, fused, cache_entries=8, batch=8):
     eng.sim.run_until(t + 1.0)
     if fused:
         assert_shadow_is_pool(op.caches[0])
-    return _final_state(op, 64), _counters(op)
+    assert sorted(sink.got) == sorted(e[:3] for e in emitted)
+    return _final_state(op, 64), _counters(op), emitted
 
 
-def assert_base_parity(keys):
-    si, ci = run_base(keys, fused=False)
-    sf, cf = run_base(keys, fused=True)
+def assert_base_parity(keys, mode="async", traced=False, **kw):
+    runs = [run_base(keys, fused, mode=mode, traced=traced, **kw)
+            for fused in (False, True)]
+    (si, ci, ei), (sf, cf, ef) = runs
     assert si == sf, f"state mismatch\ninterp={si}\nfused={sf}"
-    assert ci == cf, f"counter mismatch\ninterp={ci}\nfused={cf}"
+    _assert_counters_match(ci, cf, mode)
+    _assert_emits_match(ei, ef)
+    if traced:
+        for fused, run in enumerate(runs):
+            _assert_trace_moves_nothing(
+                run, run_base(keys, bool(fused), mode=mode, **kw))
     return ci
 
 
-def test_base_parity_with_evictions_and_parking():
+@pytest.mark.parametrize("emits", [False, True], ids=["absorbed", "emits"])
+@pytest.mark.parametrize("mode,traced", PLANES_MODES)
+def test_base_parity_with_evictions_and_parking(mode, traced, emits):
     keys = [1, 2, 3, 1, 1, 4, 2, 9, 9, 1, 5, 6, 7, 8, 10, 11, 1, 2, 12, 1]
-    ci = assert_base_parity(keys)
+    ci = assert_base_parity(keys, mode=mode, traced=traced, emits=emits)
     # the workload must actually exercise the cold paths it claims to
     assert ci["evictions"] > 0
-    assert ci["pf_demand"] > 0          # misses parked + demand-fetched
+    assert ci["pf_demand"] > 0          # misses parked or sync-fetched
+    assert ci["outputs"] == (len(keys) if emits else 0)
+    if traced:
+        assert ci["finished"] == ci["sampled"] == len(keys)
+        assert ci["probe_misses"] > 0
 
 
 def test_base_parity_single_hot_key():
@@ -147,11 +250,15 @@ def test_base_parity_property(keys):
 # -------------------------------------------------------- windowed operator
 def run_windowed(keys_ts, fused, lateness, late_policy, size=10.0,
                  cache_entries=6, batch=8, wm_lag=6.0, spec=None,
-                 agg=None, emit=None, payload_of=None):
+                 agg=None, emit=None, payload_of=None, mode="async",
+                 traced=False):
     """Windowed count per (key, window) with a mid-stream watermark after
-    every quiesced data batch; returns (emits, state, counters)."""
+    every quiesced data batch; returns (sink tuples, state, counters,
+    emits with their sim times)."""
     eng = Engine()
-    kw = dict(policy="tac", mode="async", cache_capacity=cache_entries * 64,
+    if traced:
+        eng.enable_tracing(sample_every=1)
+    kw = dict(policy="tac", mode=mode, cache_capacity=cache_entries * 64,
               state_size=64, io_workers=2, allowed_lateness=lateness,
               late_policy=late_policy)
     if fused:
@@ -169,6 +276,7 @@ def run_windowed(keys_ts, fused, lateness, late_policy, size=10.0,
     eng.add(op)
     eng.add(sink)
     eng.connect(op, sink)
+    emitted = _record_emits(op)
     batches = []                         # the fence-invariant check below
     if fused:
         plane = op.caches[0]
@@ -183,8 +291,8 @@ def run_windowed(keys_ts, fused, lateness, late_policy, size=10.0,
     for i in range(0, len(keys_ts), 6):
         chunk = keys_ts[i:i + 6]
         op.deliver_batch(0, [
-            Tuple_(ts, k, payload_of(k, ts) if payload_of else None,
-                   64, 0.0) for k, ts in chunk])
+            _source_tuple(eng, ts, k, payload_of(k, ts) if payload_of
+                          else None) for k, ts in chunk])
         hi = max([hi] + [ts for _, ts in chunk])
         t += 0.05
         eng.sim.run_until(t)             # quiesce: all I/O lands
@@ -205,15 +313,23 @@ def run_windowed(keys_ts, fused, lateness, late_policy, size=10.0,
     ctr = _counters(op)
     ctr.update(fires=op.fires, late_dropped=op.late_dropped,
                late_updates=op.late_updates, purged=op.panes_purged)
-    return sorted(sink.got), _final_state(op, 64), ctr
+    assert sorted(sink.got) == sorted(e[:3] for e in emitted)
+    return sorted(sink.got), _final_state(op, 64), ctr, emitted
 
 
-def assert_windowed_parity(keys_ts, lateness, late_policy, **kw):
-    gi, si, ci = run_windowed(keys_ts, False, lateness, late_policy, **kw)
-    gf, sf, cf = run_windowed(keys_ts, True, lateness, late_policy, **kw)
+def assert_windowed_parity(keys_ts, lateness, late_policy, mode="async",
+                           traced=False, **kw):
+    runs = [run_windowed(keys_ts, fused, lateness, late_policy, mode=mode,
+                         traced=traced, **kw) for fused in (False, True)]
+    (gi, si, ci, _), (gf, sf, cf, _) = runs
     assert gi == gf, f"emit mismatch\ninterp={gi}\nfused={gf}"
     assert si == sf, f"state mismatch\ninterp={si}\nfused={sf}"
-    assert ci == cf, f"counter mismatch\ninterp={ci}\nfused={cf}"
+    _assert_counters_match(ci, cf, mode)
+    if traced:
+        for fused, run in enumerate(runs):
+            _assert_trace_moves_nothing(
+                run[1:], run_windowed(keys_ts, bool(fused), lateness,
+                                      late_policy, mode=mode, **kw)[1:])
     return ci
 
 
@@ -229,14 +345,19 @@ def test_windowed_parity_no_lateness():
     assert ci["evictions"] > 0
 
 
-def test_windowed_parity_update_policy_with_late_tuples():
+@pytest.mark.parametrize("mode,traced", PLANES_MODES)
+def test_windowed_parity_update_policy_with_late_tuples(mode, traced):
     # watermark trails by 6s; tuples jumping 30s back are LATE on fired
     # panes (within the 40s horizon -> late-side re-aggregation)
     stream = _steady_stream()
     late = [(1, 3.0), (2, 5.0), (1, 12.0), (9, 14.0)]
     keys_ts = stream[:18] + late + stream[18:]
-    ci = assert_windowed_parity(keys_ts, 40.0, "update")
+    ci = assert_windowed_parity(keys_ts, 40.0, "update", mode=mode,
+                                traced=traced)
     assert ci["late_updates"] > 0
+    assert ci["fires"] > 0 and ci["evictions"] > 0
+    if traced:
+        assert ci["finished"] == ci["sampled"] == len(keys_ts)
 
 
 def test_windowed_parity_drop_policy_drops_late():

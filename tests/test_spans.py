@@ -218,7 +218,7 @@ def _run(query: str, seed: int, spans: bool):
     for p in planes:
         def account(slot, reason, p=p, inner=p._account_eviction):
             dirty_reads[0] += bool(p._sdirty[slot]) \
-                and slot not in p._pending_state
+                and slot not in p._pending_admits
             return inner(slot, reason)
         p._account_eviction = account
     # the benchmark's kind of wrapper on the keyed operator's input
