@@ -11,6 +11,12 @@ results; the directory after an admit or a drop).  Rounds alternate the
 two variants; the median of ``--rounds`` is reported, split into
 dispatch (the call returns) and readback (the host holds the results).
 
+The ``fused_step_deferred`` variant times what a deferred readback
+leaves: each step is dispatched, its output slab's host copy started
+(``copy_to_host_async``, "async") or not ("sync"), the host then spins
+for 0.25, 0.5, 1 or 2 ms as other work would, and the wait of the read
+that follows is reported per call.
+
     python benchmarks/fused_transfer.py        # on a host with a TPU
 
 Writes ``chiprun_out/fused_transfer.json``; exits 1 without a TPU.
@@ -33,6 +39,7 @@ import numpy as np  # noqa: E402
 from repro.core import tac_jax  # noqa: E402
 
 CELLS = [("sum", 256, 4096), ("read", 256, 65536)]
+BUSY_MS = (0.25, 0.5, 1.0, 2.0)
 
 
 def _per_array_programs(kind: str):
@@ -106,6 +113,22 @@ def measure(kind: str, B: int, W: int, calls: int, rounds: int,
         jax.block_until_ready(state)
         return {"call_us": (time.perf_counter() - t0) / calls * 1e6}
 
+    def run_deferred(busy_s, copy):
+        nonlocal state, pages
+        wait = 0.0
+        for _ in range(calls):
+            out = tac_jax.fused_step(state, pages, *step_in, kind=kind)
+            state, pages = out.state, out.pages
+            if copy:
+                out.out.copy_to_host_async()
+            until = time.perf_counter() + busy_s
+            while time.perf_counter() < until:
+                pass
+            ta = time.perf_counter()
+            out.read()
+            wait += time.perf_counter() - ta
+        return {"wait_us": wait / calls * 1e6}
+
     runs = {"fused_step": run_step, "fused_admit": run_admit,
             "drop_slots": run_drop}
     res = {}
@@ -119,6 +142,15 @@ def measure(kind: str, B: int, W: int, calls: int, rounds: int,
                          for k in rs[0]} for v, rs in got.items()}
         res[name]["saved_us"] = round(res[name]["per_array"]["call_us"]
                                       - res[name]["slab"]["call_us"], 2)
+    deferred = res["fused_step_deferred"] = {}
+    for ms in BUSY_MS:
+        got = {"async": [], "sync": []}
+        for _ in range(rounds):
+            for v, waits in got.items():
+                waits.append(run_deferred(ms / 1e3, v == "async")["wait_us"])
+        deferred[f"busy_{ms}ms"] = {
+            f"{v}_wait_us": round(statistics.median(w), 2)
+            for v, w in got.items()}
     return res
 
 

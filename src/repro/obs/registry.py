@@ -387,6 +387,17 @@ METRIC_CATALOG: Dict[str, str] = {
     "engine.<op>.fused.shadow_reads":
         "rows served from the host value shadow (victims and slot "
         "reads), each in place of a device gather",
+    "engine.<op>.fused.deferred":
+        "fused steps whose output slab was first waited on at the "
+        "plane's next sync, not on the way back from their own batch",
+    "engine.<op>.fused.forced_settles":
+        "fused steps settled before the plane's next sync, by a lane "
+        "value (fire, late update, emit) or an access to a slot the "
+        "step updated",
+    "engine.<op>.fused.hit_mismatches":
+        "fused steps whose device hits, slots or tallies differed from "
+        "the host directory's prediction: a fault under the plane, 0 "
+        "in a sound run",
     # wall-clock host spans (repro.obs.spans; on after enable_spans):
     # <owner> is an operator, channel, engine, or fused (plane phases)
     "engine.span.<owner>.<name>.count": "spans closed",

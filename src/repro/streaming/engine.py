@@ -2023,6 +2023,11 @@ class Engine:
                                       for d in FusedPlane.TRANSFERS},
                         "victim_reads": sum(c.victim_reads for c in fp),
                         "shadow_reads": sum(c.shadow_reads for c in fp),
+                        "deferred": sum(c.deferred for c in fp),
+                        "forced_settles": sum(c.forced_settles
+                                              for c in fp),
+                        "hit_mismatches": sum(c.hit_mismatches
+                                              for c in fp),
                     }
                 if op.shards is not None:
                     # per-shard routed-plane counters (DESIGN.md §9), not
@@ -2164,6 +2169,12 @@ class Engine:
                     sum(c.victim_reads for c in fp))
                 r.counter(f"{pre}.fused.shadow_reads").set(
                     sum(c.shadow_reads for c in fp))
+                r.counter(f"{pre}.fused.deferred").set(
+                    sum(c.deferred for c in fp))
+                r.counter(f"{pre}.fused.forced_settles").set(
+                    sum(c.forced_settles for c in fp))
+                r.counter(f"{pre}.fused.hit_mismatches").set(
+                    sum(c.hit_mismatches for c in fp))
             if op.shards is not None:
                 op.shards.registry_sync(r, pre, op.shard_pending)
         if self.spans.enabled:

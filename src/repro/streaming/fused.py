@@ -23,8 +23,13 @@ Two data structures cooperate:
     change only through the entry points below, so they agree by
     construction.  A value shadow (``_sval``/``_spres``) keeps a copy
     of every occupied slot's pool row, written where the device's two
-    writers write (``_place`` for ``fused_admit``, ``batch_step`` for
+    writers write (``_place`` for ``fused_admit``, ``_settle`` for
     ``fused_step``), so single-row reads never cross the bus.
+
+``batch_step`` takes each lane's hit and slot from the host directory
+and does not wait for the step's output slab: its host copy starts at
+dispatch, and the step settles (slab read, hits checked, value shadow
+written) only when a value is needed or at the plane's next ``_sync``.
 
 ``FusedPlane`` implements the full ``TimestampAwareCache`` interface
 (lookup/insert/write/renew/drop/pop_writeback/flush_dirty/export/import/
@@ -125,12 +130,42 @@ class Lane(NamedTuple):
     tup: Any                  # source Tuple_ (parking, traces, emits)
 
 
-class BatchResult(NamedTuple):
-    hit: np.ndarray           # [n] bool — device-resident, update applied
-    present: np.ndarray       # [n] bool — value present after the lane
-    new_vals: np.ndarray      # [n, V]  — value after the lane (composed)
-    fire: np.ndarray          # [n] bool — the staged fire flags (lets
-    #                           the caller mask lanes without re-walking)
+class BatchResult:
+    """One fused batch's per-lane results (§14, deferred readback).
+
+    ``hit`` [n] bool (device-resident, update applied) comes from the
+    host directory at dispatch, ``fire`` [n] bool is the staged fire
+    flags (lets the caller mask lanes without re-walking); both are
+    plain arrays.  ``new_vals`` [n, V] (value after the lane, composed)
+    and ``present`` [n] bool (value present after the lane) are the
+    device's: the first access settles the step, waiting on its output
+    slab if the plane has not read it yet."""
+    __slots__ = ("hit", "fire", "_plane", "_vals")
+
+    def __init__(self, plane: "FusedPlane", hit: np.ndarray,
+                 fire: np.ndarray):
+        self.hit, self.fire = hit, fire
+        self._plane = plane
+        self._vals: Optional[Tuple[np.ndarray, np.ndarray]] = None
+
+    def _values(self) -> Tuple[np.ndarray, np.ndarray]:
+        if self._vals is None:            # still the outstanding step
+            self._plane._settle(forced=True)
+        return self._vals
+
+    new_vals = property(lambda self: self._values()[0])
+    present = property(lambda self: self._values()[1])
+
+
+class _Step(NamedTuple):
+    """The plane's outstanding ``fused_step``: its output slab, the
+    host's prediction of the lanes' ``hit`` and ``slots``, and the
+    slots its update lanes wrote, each with its last update lane."""
+    out: Any                  # tac_jax.FusedStep, slab copy under way
+    hit: np.ndarray           # [n] bool
+    slots: np.ndarray         # [n] slot, the scratch row W for misses
+    writes: Dict[int, int]    # updated slot -> lane of its last update
+    res: BatchResult
 
 
 class FusedPlane:
@@ -236,6 +271,15 @@ class FusedPlane:
         self.transfers: Dict[str, int] = dict.fromkeys(self.TRANSFERS, 0)
         self.victim_reads = 0
         self.shadow_reads = 0
+        # deferred readback (§14): the step whose output slab is not
+        # read yet; steps settled at the plane's next _sync (deferred)
+        # or earlier, by a lane value or an access to a slot the step
+        # wrote (forced); steps whose device hits, slots or tallies
+        # differ from the host directory's prediction (a fault: 0)
+        self._step: Optional[_Step] = None
+        self.deferred = 0
+        self.forced_settles = 0
+        self.hit_mismatches = 0
 
     # ------------------------------------------------------------ internals
     def _intern(self, key) -> int:
@@ -309,8 +353,52 @@ class FusedPlane:
             spans.exit()
 
     def _sync(self) -> None:
+        self._settle()
         self._flush_drops()
         self._flush_admits()
+
+    def _settle(self, forced: bool = False) -> None:
+        """Read the outstanding step's output slab (its host copy began
+        at dispatch), hold the device's hits to the host's prediction,
+        and write each updated slot's row into the value shadow.
+        ``forced``: a lane value or a slot access asked for it before
+        the plane's next ``_sync``.
+
+        A device whose hits, slots or tallies differ from the host
+        directory's is a fault under the plane (an admission or update
+        that did not land): the step counts in ``hit_mismatches`` and
+        the run goes on, its results the device's, for the checks
+        downstream to judge."""
+        step = self._step
+        if step is None:
+            return
+        self._step = None
+        spans = self.spans
+        if spans.enabled:
+            spans.enter("stream.fused.readback")
+        out = step.out.read()
+        if spans.enabled:
+            spans.exit()
+        n = len(step.hit)
+        hits = int(step.hit.sum())
+        if not (np.array_equal(out["hit"][:n], step.hit)
+                and np.array_equal(out["slots"][:n], step.slots)
+                and out["tallies"].tolist() == [hits, n - hits]):
+            self.hit_mismatches += 1
+        new_vals, present = out["new_vals"][:n], out["present"][:n]
+        if step.writes:
+            # value shadow: the row the device scattered, i.e. each
+            # updated slot's LAST update lane (last-write-wins)
+            upd = np.fromiter(step.writes, np.int64, len(step.writes))
+            last = np.fromiter(step.writes.values(), np.int64,
+                               len(step.writes))
+            self._sval[upd] = new_vals[last]
+            self._spres[upd] = present[last]
+        step.res._vals = (new_vals, present)
+        if forced:
+            self.forced_settles += 1
+        else:
+            self.deferred += 1
 
     def _touch(self, slot: int) -> None:
         self._touched.add(slot)
@@ -409,6 +497,8 @@ class FusedPlane:
             else:
                 slot, evict_reason = self._choose_victim()
             self.used += self.entry_size
+        if self._step is not None and slot in self._step.writes:
+            self._settle(forced=True)
         if evict_reason is not None:
             self._account_eviction(slot, evict_reason)
         vec, present = self.spec.enc(state)
@@ -433,6 +523,8 @@ class FusedPlane:
         """One slot's row from the value shadow, decoded: what the pool
         holds there, or will once the queued admissions land, with no
         device call.  Timed under ``span`` unless it is None."""
+        if self._step is not None and slot in self._step.writes:
+            self._settle(forced=True)
         on = span is not None and self.spans.enabled
         if on:
             self.spans.enter(span)
@@ -539,6 +631,7 @@ class FusedPlane:
 
     # ------------------------------------------------------- bulk/cold ops
     def _pool_host(self) -> np.ndarray:
+        self._settle()
         self._flush_admits()
         spans = self.spans
         if spans.enabled:
@@ -632,9 +725,18 @@ class FusedPlane:
         hit/miss counters exactly sequential-equivalent.  Device tallies
         fold into ``device_hits``/``device_misses``.
 
+        Deferred readback (§14): after ``_sync`` the host directory is
+        the device's, so each lane's hit and slot come from
+        ``_slot_by_key`` and the shadow's ts, gen and dirty advance at
+        dispatch.  The output slab's host copy starts with the call and
+        is read when the step settles (``_settle``): on the first
+        access to a lane value, before a read or overwrite of a slot
+        the step updated, or at the plane's next ``_sync``.
+
         With spans on, the call splits into ``stream.fused.stage``
-        (flushes and staging), ``dispatch`` (the jitted call),
-        ``readback`` (the host blocked on the output slab) and ``shadow``.
+        (flushes, staging, the host's hits), ``dispatch`` (the jitted
+        call and the copy's start) and ``shadow``; ``readback`` (the
+        host blocked on the output slab) opens wherever a step settles.
         """
         n = len(lanes)
         B = self.batch
@@ -646,11 +748,18 @@ class FusedPlane:
             spans.enter("stream.fused.stage")
         self._sync()
         V = self.spec.width
+        W = self.n_slots
         # bulk staging: one fromiter/asarray per field beats per-lane
         # numpy scalar writes by ~50x at B=64
         keys = np.full(B, self.PAD_KEY, np.int32)
         keys[:n] = np.fromiter((self._intern(ln.key) for ln in lanes),
                                np.int64, n)
+        # the device probe's answer, known here: misses point at the
+        # scratch row W, as the device reports them
+        slot_of = self._slot_by_key.get
+        slots = np.fromiter((slot_of(ln.key, W) for ln in lanes),
+                            np.int64, n)
+        hit = slots < W
         ts64 = np.fromiter((ln.ts for ln in lanes), np.float64, n)
         ts32 = np.zeros(B, np.float32)
         ts32[:n] = ts64
@@ -669,21 +778,15 @@ class FusedPlane:
                                   weights, fire, valid,
                                   kind=self.spec.kind)
         self.tac, self.pages = out.state, out.pages
-        if on:
-            spans.switch("stream.fused.readback")
-        res = out.read()
+        out.out.copy_to_host_async()
         self.transfers["to_host"] += 1
-        hit = np.asarray(res["hit"])[:n]
-        slots = np.asarray(res["slots"])[:n]
-        new_vals = np.asarray(res["new_vals"])[:n]
-        present = np.asarray(res["present"])[:n]
-        tallies = np.asarray(res["tallies"])
         if on:
             spans.switch("stream.fused.shadow")
         self.batches += 1
         self.lanes += n
-        misses = int(tallies[1])
-        self.device_hits += int(tallies[0])
+        hits = int(hit.sum())
+        misses = n - hits
+        self.device_hits += hits
         self.device_misses += misses
         # conflict tally (§12): misses in excess of the slots free (or
         # already queued to free) when the batch was adjudicated — each
@@ -692,9 +795,10 @@ class FusedPlane:
         free_now = len(self._free) + len(self._pending_drops)
         if misses > free_now:
             self.device_conflicts += misses - free_now
-        self.hits += int(tallies[0])
+        self.hits += hits
+        writes: Dict[int, int] = {}
         # shadow advance for hit lanes, vectorized (fp64 order + dirty)
-        if hit.any():
+        if hits:
             hs = slots[hit]
             hts = ts64[hit]
             cur = self._sts[hs]
@@ -708,26 +812,24 @@ class FusedPlane:
                 self._gen += len(adv)
                 self._touched.update(adv.tolist())
             if self.spec.kind != "read":
-                upd = hit & ~fire[:n]
-                self._sdirty[slots[upd]] = True
-                # value shadow: the row the device scattered, i.e. each
-                # updated slot's LAST update lane (last-write-wins),
-                # chosen explicitly rather than by numpy's order for
-                # repeated fancy-index assignment
-                ui = np.flatnonzero(upd)[::-1]
-                us, first = np.unique(slots[ui], return_index=True)
-                last = ui[first]
-                self._sval[us] = new_vals[last]
-                self._spres[us] = present[last]
+                ui = np.flatnonzero(hit & ~fire[:n])
+                self._sdirty[slots[ui]] = True
+                # the slots the step writes, each with its LAST update
+                # lane (the scatter's last-write-wins: a later lane of
+                # a slot replaces the earlier in the dict); their
+                # values land at settle
+                writes = dict(zip(slots[ui].tolist(), ui.tolist()))
             # first read of staged entries: signed lead time (§12)
             first = hs[self._spf_unused[hs]]
             if len(first) and self.recorder is not None:
                 for s in np.unique(first):
                     self.recorder.on_used(float(self._sstage_t[s]))
             self._spf_unused[hs] = False
+        res = BatchResult(self, hit, fire[:n])
+        self._step = _Step(out, hit, slots, writes, res)
         if on:
             spans.exit()
-        return BatchResult(hit, present, new_vals, fire[:n])
+        return res
 
     def decode_lane(self, res: BatchResult, i: int):
         return self.spec.dec(res.new_vals[i], bool(res.present[i]))

@@ -143,9 +143,13 @@ def _final_state(op, state_size):
 
 def assert_shadow_is_pool(plane):
     """The host value shadow holds, bit for bit, the pool row of every
-    occupied slot once the queued admissions have landed."""
+    occupied slot once the queued admissions have landed; every step
+    has settled, and its device hits were the host's."""
     import numpy as np
     plane._sync()
+    assert plane.deferred + plane.forced_settles == \
+        plane.calls["fused_step"]
+    assert plane.hit_mismatches == 0
     pool = np.asarray(plane.pages)
     occ = np.asarray(sorted(plane._slot_by_key.values()), np.int64)
     if not len(occ):
@@ -731,3 +735,169 @@ def test_one_transfer_each_way_per_device_call_in_query_runs(query):
             if fp:
                 assert reg[f"engine.{name}.fused.transfers.{d}"] == \
                     sum(c.transfers[d] for c in fp)
+
+
+# ------------------------------------------------------ deferred readback
+def _plane_with_outstanding_update():
+    """A plane of two slots holding "k" (10, clean) and "j" (1), with one
+    step outstanding that adds 1 to "k" twice."""
+    spec = count_spec()
+    plane = FusedPlane(2 * 8, 8, spec, batch=8)
+    plane.insert("k", 10, 1.0, dirty=False)
+    plane.insert("j", 1, 1.5, dirty=False)
+    res = plane.batch_step([Lane("k", 2.0, (1.0,), False, False, None)
+                            for _ in range(2)])
+    return plane, res
+
+
+def test_plain_update_step_defers_its_readback():
+    """A plain update step returns without reading its output slab; the
+    host's hits stand in for the device's, and the slab is read once,
+    at the plane's next step, which counts it as deferred."""
+    import numpy as np
+    plane, res = _plane_with_outstanding_update()
+    reads, out = [0], plane._step.out
+    real_read = out.read
+
+    def counting_read():
+        reads[0] += 1
+        return real_read()
+    out.read = counting_read
+    assert res.hit.tolist() == [True, True]
+    assert plane.device_hits == 2 and plane.hits == 2
+    assert plane._sdirty[plane._slot_by_key["k"]]
+    assert plane.lookup("j", 2.0) == 1      # a slot the step left alone
+    assert reads[0] == 0 and plane.deferred == plane.forced_settles == 0
+    plane.batch_step([Lane("j", 3.0, (1.0,), False, False, None)])
+    assert reads[0] == 1
+    assert plane.deferred == 1 and plane.forced_settles == 0
+    assert [plane.decode_lane(res, i) for i in range(2)] == [11, 12]
+    assert plane.forced_settles == 0 and reads[0] == 1
+    assert isinstance(res.new_vals, np.ndarray)
+    assert_shadow_is_pool(plane)
+    assert plane.deferred == 2              # the second step, at _sync
+
+
+@pytest.mark.parametrize("field", ["hit", "slots"])
+def test_settle_counts_a_device_that_differs_from_the_host(field):
+    """A device whose answer is not the host directory's is a fault under
+    the plane: the settle counts it and hands on the device's values."""
+    import numpy as np
+    plane, res = _plane_with_outstanding_update()
+    step = plane._step
+    wrong = ~step.hit if field == "hit" else np.zeros_like(step.slots) + 1
+    plane._step = step._replace(**{field: wrong})
+    plane._sync()
+    assert plane.hit_mismatches == 1 and plane.deferred == 1
+    assert [plane.decode_lane(res, i) for i in range(2)] == [11, 12]
+    plane.batch_step([Lane("j", 3.0, (1.0,), False, False, None)])
+    plane._sync()
+    assert plane.hit_mismatches == 1        # the next step agrees
+
+
+def _evict_k(plane):
+    plane.renew("j", 3.0)
+    plane.insert("m", 0, 4.0, dirty=False)   # evicts "k", dirty
+    return plane.evict_buffer["k"].state
+
+
+def _drop_k_insert_n(plane):
+    slot = plane._slot_by_key["k"]
+    plane.drop("k")
+    plane.insert("n", 7, 3.0, dirty=True)    # takes k's freed slot
+    assert plane._slot_by_key["n"] == slot
+    return plane.lookup("n", 3.0)
+
+
+def _overwrite_k(plane):
+    plane.write("k", 50, 3.0)
+    return plane.lookup("k", 3.0)
+
+
+@pytest.mark.parametrize("access,want", [
+    (_evict_k, 12), (lambda p: p.lookup("k", 3.0), 12),
+    (_overwrite_k, 50), (_drop_k_insert_n, 7)],
+    ids=["dirty_victim", "lookup", "overwrite", "drop_then_insert"])
+def test_slot_access_settles_the_outstanding_step_first(access, want):
+    """A dirty victim, a slot read, an overwriting write and a new key in
+    a slot the step updated each settle the step before touching the
+    slot: they see its value, and the settle never lands on the newer
+    occupant."""
+    plane, _ = _plane_with_outstanding_update()
+    assert access(plane) == want
+    assert plane.forced_settles == 1 and plane.deferred == 0
+    assert plane._step is None
+    assert_shadow_is_pool(plane)
+    assert plane.calls["gather_rows"] == 0
+
+
+@pytest.mark.parametrize("fire,late", [(True, False), (False, True)],
+                         ids=["fire", "late_update"])
+def test_fire_and_late_lanes_decode_the_device_values(fire, late):
+    import numpy as np
+    spec = count_spec()
+    plane = FusedPlane(4 * 8, 8, spec, batch=8)
+    plane.insert("p", 5, 1.0, dirty=False)
+    w = (0.0,) if fire else (1.0,)
+    res = plane.batch_step([Lane("p", 2.0, w, fire, late, None)])
+    out = plane._step.out
+    assert plane.decode_lane(res, 0) == (5 if fire else 6)
+    assert plane.forced_settles == 1 and plane.deferred == 0
+    dev = out.read()
+    assert np.array_equal(res.new_vals, dev["new_vals"][:1])
+    assert np.array_equal(res.present, dev["present"][:1])
+    assert_shadow_is_pool(plane)
+
+
+@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign"])
+def test_host_predicts_the_device_hits_in_query_runs(query):
+    """Every step of each deployment the benchmark runs settles, and so
+    has its device hits, slots and tallies held to the host directory's.  q5's plain updates defer; every lane of the
+    read join emits, so its steps settle at once."""
+    eng = _query_engine(query, 17)
+    planes = [c for op in eng.operators.values()
+              if isinstance(op, StatefulOp) for c in op.caches
+              if isinstance(c, FusedPlane)]
+    eng.run(duration=2.0)
+    for plane in planes:
+        plane._sync()
+        steps = plane.calls["fused_step"]
+        assert steps > 10
+        assert plane.deferred + plane.forced_settles == steps
+        assert plane.hit_mismatches == 0
+        share = plane.deferred / steps
+        if plane.spec.kind == "read":
+            assert share < 0.1
+        else:
+            assert share >= 0.7, (query, share)
+    eng._sync_registry()
+    reg = eng.registry.snapshot()
+    for name, op in eng.operators.items():
+        fp = [c for c in getattr(op, "caches", ()) if c in planes]
+        if fp:
+            for k in ("deferred", "forced_settles", "hit_mismatches"):
+                assert reg[f"engine.{name}.fused.{k}"] == \
+                    sum(getattr(c, k) for c in fp)
+
+
+def test_admissions_that_never_land_are_counted_not_fatal(monkeypatch):
+    """A device whose admissions never land answers misses where the host
+    directory holds the keys: every such step counts in
+    ``hit_mismatches`` and the run goes on to its end, its results the
+    device's, for a comparison downstream to judge."""
+    from repro.core import tac_jax
+    real_admit = tac_jax.fused_admit
+
+    def admit(state, pages, *a):
+        return state, pages, real_admit(state, pages, *a)[2]
+    monkeypatch.setattr(tac_jax, "fused_admit", admit)
+    eng = _query_engine("q5", 17)
+    planes = [c for op in eng.operators.values()
+              if isinstance(op, StatefulOp) for c in op.caches
+              if isinstance(c, FusedPlane)]
+    eng.run(duration=1.0)
+    for plane in planes:
+        plane._sync()
+    assert sum(p.hit_mismatches for p in planes) > 0
+    assert all(p.deferred + p.forced_settles == p.calls["fused_step"]
+               for p in planes)

@@ -114,6 +114,17 @@ def print_fused(fb: dict) -> None:
           f"(dirty victims with no queued row)")
     print(f"  {'shadow reads':<16s} {fb.get('shadow_reads', 0):>8d}   "
           f"(rows served from the host value shadow)")
+    if calls.get("fused_step"):
+        for label, k, why in (
+                ("deferred/step", "deferred",
+                 "slab first waited on at the plane's next step"),
+                ("forced/step", "forced_settles",
+                 "settled early by a lane value or slot access")):
+            print(f"  {label:<16s} "
+                  f"{fb.get(k, 0) / calls['fused_step']:>8.3f}   ({why})")
+    print(f"  {'hit mismatches':<16s} {fb.get('hit_mismatches', 0):>8d}   "
+          f"(steps the device answered otherwise than the host "
+          f"directory; 0 in a sound run)")
 
 
 def print_spans(spans: dict) -> None:
