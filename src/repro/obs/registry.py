@@ -398,6 +398,28 @@ METRIC_CATALOG: Dict[str, str] = {
         "fused steps whose device hits, slots or tallies differed from "
         "the host directory's prediction: a fault under the plane, 0 "
         "in a sound run",
+    # session windows (§15): registry decisions, made in input order,
+    # and how the merge protocol met the plane
+    "engine.<op>.sessions.created": "session windows opened",
+    "engine.<op>.sessions.merged":
+        "sessions absorbed into another by a bridging or predating tuple",
+    "engine.<op>.sessions.reopened":
+        "fired sessions re-opened by a late tuple inside the lateness",
+    "engine.<op>.sessions.fires_superseded":
+        "fire deadlines a later tuple moved (extensions of an open "
+        "session): each one a hint deadline renewed",
+    "engine.<op>.sessions.fires_absorbed":
+        "sessions merged away before they fired: their count fires "
+        "with the survivor",
+    "engine.<op>.sessions.rehints":
+        "lookahead re-hints of a moved session deadline (bypass "
+        "admission)",
+    "engine.<op>.sessions.held":
+        "pane accesses held behind absorbs in flight (a drain parked "
+        "on its fetch)",
+    "engine.<op>.sessions.merge_settles":
+        "merge drains and absorbs whose pane read settled the "
+        "outstanding fused step early",
     # wall-clock host spans (repro.obs.spans; on after enable_spans):
     # <owner> is an operator, channel, engine, or fused (plane phases)
     "engine.span.<owner>.<name>.count": "spans closed",

@@ -191,14 +191,18 @@ class OracleReport:
         return "\n".join(lines)
 
 
-def build_chaos_engine(seed: int, mode: str = "prefetch") -> Engine:
+def build_chaos_engine(seed: int, mode: str = "prefetch",
+                       fused: bool = False) -> Engine:
+    """The chaos workload's q11 engine: on the sharded plane, or with
+    ``fused`` on the fused device plane, which runs unsharded (§14)."""
     cfg = NexmarkConfig(rate=RATE, seed=seed, oo_bound=OO_BOUND,
                         watermark_interval=0.05)
     return build_query("q11", "tac", mode, cfg, cache_entries=512,
                        parallelism=PARALLELISM, source_parallelism=1,
-                       io_workers=4, n_shards=N_SHARDS,
+                       io_workers=4, n_shards=None if fused else N_SHARDS,
                        buffer_timeout=0.002, session_gap=GAP,
-                       allowed_lateness=LATENESS, replayable=True)
+                       allowed_lateness=LATENESS, replayable=True,
+                       fused=fused, fused_batch=64)
 
 
 def _install(eng: Engine, coord: CheckpointCoordinator,
@@ -251,7 +255,8 @@ def _install(eng: Engine, coord: CheckpointCoordinator,
 
 def run_schedule(schedule: FaultSchedule, t_cut: float = 2.0,
                  mode: str = "prefetch", observe: bool = False,
-                 timeline_interval: float = 0.1) -> RunResult:
+                 timeline_interval: float = 0.1,
+                 fused: bool = False) -> RunResult:
     """Run the chaos workload under ``schedule`` until the source's
     LOGICAL clock reaches ``t_cut``, quiesce, then flush all windows
     with a final watermark pair and collect the oracle observables.
@@ -269,8 +274,13 @@ def run_schedule(schedule: FaultSchedule, t_cut: float = 2.0,
     the harness, not the run).  The alerts land in
     ``RunResult.metrics["alerts"]`` for the alert oracle
     (``alert_oracle``).
+
+    ``fused`` runs the query on the fused device plane; that plane has
+    no shards, so a schedule with a migration cannot run there.
     """
-    eng = build_chaos_engine(schedule.seed, mode=mode)
+    if fused and "migrate" in schedule.kinds():
+        raise ValueError("the fused plane runs unsharded: no migrations")
+    eng = build_chaos_engine(schedule.seed, mode=mode, fused=fused)
     sim = eng.sim
     src: SourceOp = eng.operators["source"]
     op = eng.operators["stateful"]
@@ -525,13 +535,14 @@ def _count_kinds(alerts: List[dict]) -> Dict[str, int]:
 
 def check_schedule(schedule: FaultSchedule, t_cut: float = 2.0,
                    golden: Optional[RunResult] = None,
-                   mode: str = "prefetch"):
+                   mode: str = "prefetch", fused: bool = False):
     """Run golden (zero events, same seed) + perturbed and compare.
     Returns (report, golden, perturbed); pass ``golden`` to amortize it
     across schedules sharing a workload seed."""
     if golden is None:
-        golden = run_schedule(schedule.with_events(()), t_cut, mode=mode)
-    perturbed = run_schedule(schedule, t_cut, mode=mode)
+        golden = run_schedule(schedule.with_events(()), t_cut, mode=mode,
+                              fused=fused)
+    perturbed = run_schedule(schedule, t_cut, mode=mode, fused=fused)
     return compare(golden, perturbed), golden, perturbed
 
 

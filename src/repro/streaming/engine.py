@@ -1365,7 +1365,9 @@ class StatefulOp(Operator):
     def _fused_prospect(self, sub: int, tup: Tuple_):
         """PURE preview of the state keys ``tup`` will touch and whether
         it is a window fire — drives the batch conflict check (a fire
-        and an update of the same key never share a batch, §14)."""
+        and an update of the same key never share a batch, §14).  None
+        marks a control step of the operator's own (a session merge,
+        §15): it runs through ``_on_data`` at a batch boundary."""
         return (tup.key,), False
 
     def _fused_expand(self, sub: int, tup: Tuple_,
@@ -1392,7 +1394,10 @@ class StatefulOp(Operator):
         it through the device plane (§14).  Assembly stops at the batch
         width, at the first non-data message, or at a fire/update
         conflict (the conflicting tuple waits for the next batch, which
-        preserves sequential per-key semantics)."""
+        preserves sequential per-key semantics).  A control step at the
+        head runs first, on the host; one behind lanes ends the batch,
+        and so does work it resumed (``ready`` runs ahead of the
+        queue)."""
         spans = self.engine.spans
         if spans.enabled:
             spans.enter(self._span_drain)
@@ -1402,9 +1407,20 @@ class StatefulOp(Operator):
         fire_keys: set = set()
         upd_keys: set = set()
         n_tuples = 0
+        svc = 0.0
         while q and isinstance(q[0], Tuple_):
             tup = q[0]
-            keys, is_fire = self._fused_prospect(sub, tup)
+            prospect = self._fused_prospect(sub, tup)
+            if prospect is None:
+                if lanes:
+                    break
+                q.popleft()
+                self.processed += 1
+                svc += self._on_data(sub, tup)
+                if self.ready[sub]:
+                    break
+                continue
+            keys, is_fire = prospect
             fence = upd_keys if is_fire else fire_keys
             if fence and any(k in fence for k in keys):
                 break
@@ -1419,7 +1435,7 @@ class StatefulOp(Operator):
             lanes.extend(new)
             if len(lanes) >= B:
                 break
-        svc = 5e-7 * n_tuples           # dequeue + expand, per tuple
+        svc += 5e-7 * n_tuples          # dequeue + expand, per tuple
         # a single tuple expanding wider than the batch runs chunked —
         # in-order chunks of one drain preserve per-key sequencing
         for i in range(0, len(lanes), B):
@@ -2114,6 +2130,8 @@ class Engine:
             lag = self._wm_lag(op)
             if lag is not None:
                 r.gauge(f"{pre}.watermark.lag").set(lag)
+            for k, v in getattr(op, "registry_counters", dict)().items():
+                r.counter(f"{pre}.{k}").set(v)
             if not isinstance(op, StatefulOp):
                 continue
             r.counter(f"{pre}.cache.hits").set(

@@ -631,8 +631,9 @@ class FusedPlane:
 
     # ------------------------------------------------------- bulk/cold ops
     def _pool_host(self) -> np.ndarray:
-        self._settle()
-        self._flush_admits()
+        # the queued drops land first, as at every sync: an admission
+        # queued to a slot a drop freed must not be cleared after it
+        self._sync()
         spans = self.spans
         if spans.enabled:
             spans.enter("stream.fused.pool_read")

@@ -248,12 +248,12 @@ def build_query(query: str, policy: str, mode: str, cfg: NexmarkConfig,
 
     ``fused=True`` runs the stateful operator's hot path as one jitted
     device program per batch (DESIGN.md §14).  Only queries whose
-    aggregation is declarative — q5 (windowed count = sum of ones) and
-    q7 (windowed max bid) — compile; ``fused_batch`` sets the device
-    batch width B."""
-    if fused and query not in ("q5", "q7"):
+    aggregation is declarative — q5 (windowed count = sum of ones), q7
+    (windowed max bid) and q11 (session count) — compile;
+    ``fused_batch`` sets the device batch width B."""
+    if fused and query not in ("q5", "q7", "q11"):
         raise ValueError(f"query {query!r} has no fused spec "
-                         "(fused mode covers q5/q7, DESIGN.md §14)")
+                         "(fused mode covers q5/q7/q11, DESIGN.md §14)")
     if query in ("q5", "q7"):
         return _build_windowed_query(
             query, policy, mode, cfg, cache_entries, backend, parallelism,
@@ -266,7 +266,7 @@ def build_query(query: str, policy: str, mode: str, cfg: NexmarkConfig,
             query, policy, mode, cfg, cache_entries, backend, parallelism,
             source_parallelism, io_workers, cms_conf, n_shards,
             buffer_timeout, hint_ts, session_gap, allowed_lateness,
-            replayable, hint_filter, compress_hints)
+            replayable, hint_filter, compress_hints, fused, fused_batch)
     if query == "q8" or (query == "q20" and cfg.oo_bound > 0):
         return _build_join_query(
             query, policy, mode, cfg, cache_entries, backend, parallelism,
@@ -447,6 +447,15 @@ def build_query(query: str, policy: str, mode: str, cfg: NexmarkConfig,
     return eng
 
 
+def _count_spec():
+    """The device form of a count per pane (a sum of ones), exact in f32
+    for counts < 2^24 (DESIGN.md §14)."""
+    from repro.streaming.fused import FusedSpec
+    return FusedSpec(kind="sum", width=1, weight_of=lambda tup: 1.0,
+                     encode=lambda s: None if s is None else [float(s)],
+                     decode=lambda v: int(round(float(v[0]))))
+
+
 def _build_windowed_query(query, policy, mode, cfg, cache_entries, backend,
                           parallelism, source_parallelism, io_workers,
                           cms_conf, n_shards, buffer_timeout, hint_ts,
@@ -505,11 +514,7 @@ def _build_windowed_query(query, policy, mode, cfg, cache_entries, backend,
         # counts < 2^24 and prices <= 10_000
         from repro.streaming.fused import FusedSpec
         if query == "q5":
-            spec = FusedSpec(
-                kind="sum", width=1,
-                weight_of=lambda tup: 1.0,
-                encode=lambda s: None if s is None else [float(s)],
-                decode=lambda v: int(round(float(v[0]))))
+            spec = _count_spec()
         else:                             # q7: running max bid
             spec = FusedSpec(
                 kind="max", width=1,
@@ -582,10 +587,12 @@ def _build_session_query(query, policy, mode, cfg, cache_entries, backend,
                          parallelism, source_parallelism, io_workers,
                          cms_conf, n_shards, buffer_timeout, hint_ts,
                          session_gap, allowed_lateness, replayable=False,
-                         hint_filter=None, compress_hints=False):
+                         hint_filter=None, compress_hints=False,
+                         fused=False, fused_batch=64):
     """NEXMark q11 (simplified): per-BIDDER activity sessions — bid count
     per session, a session closing after ``session_gap`` of inactivity
-    (DESIGN.md §15).
+    (DESIGN.md §15).  A session's count carries its end as ``ingest_t``,
+    so sink latency runs from the session's end to delivery.
 
     The only window type whose fire deadline is data-driven: every bid
     extends its session's end and a bridging bid MERGES two sessions, so
@@ -637,6 +644,10 @@ def _build_session_query(query, policy, mode, cfg, cache_entries, backend,
         # identify WHICH session a count belongs to
         return ("session", key, wid, acc) if acc else None
 
+    # the device form of agg_fn; merges run merge_fn on the host (§15)
+    fused_kw = dict(fused=_count_spec(), fused_batch=fused_batch) \
+        if fused else {}
+
     src = eng.add(SourceOp(eng, "source", source_parallelism, cfg.rate,
                            gen, watermark_interval=cfg.watermark_interval,
                            oo_bound=cfg.oo_bound, replayable=replayable))
@@ -657,7 +668,7 @@ def _build_session_query(query, policy, mode, cfg, cache_entries, backend,
         allowed_lateness=lateness, late_policy=late_policy, policy=policy,
         mode=mode, io_workers=io_workers, state_size=state_size,
         miss_threshold=1.01, deadline_aware=(hint_ts == "deadline"),
-        shards=plane))
+        shards=plane, latency_from_end=True, **fused_kw))
     sink = eng.add(SinkOp(eng, "sink", 1))
 
     from repro.streaming.engine import BUFFER_TIMEOUT

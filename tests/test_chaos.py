@@ -69,6 +69,31 @@ def test_oracle_passes_under_concurrent_faults():
     assert perturbed.metrics["fires"] > 0
 
 
+@pytest.mark.parametrize("seed", [101, 2**31 + 7])
+def test_oracle_passes_over_fused_q11(seed):
+    """The same oracle over q11 on the fused device plane, which runs
+    unsharded: a failure, a load shift and a hint-drop window leave its
+    final keyed state, session registry and last emit per pane as its
+    golden run's — and the fused golden run's are the interpreted
+    one's (on the sharded plane)."""
+    sched = FaultSchedule(seed, (
+        FaultEvent("hint_drop", 0.3, (0.6, 0.5)),
+        FaultEvent("failure", 0.55, ("warmed",)),
+        FaultEvent("load_shift", 0.7, (2.0, 0.4))), chaos_seed=seed % 997)
+    report, golden, perturbed = check_schedule(sched, t_cut=T_CUT,
+                                               fused=True)
+    assert report.ok, report.violations[:3]
+    assert perturbed.metrics["failures"] >= 1
+    assert golden.registry and golden.last_emit
+    interp = run_schedule(sched.with_events(()), t_cut=T_CUT)
+    assert golden.final_state == interp.final_state
+    assert golden.registry == interp.registry
+    assert golden.last_emit == interp.last_emit
+    with pytest.raises(ValueError):
+        run_schedule(sched.with_events([FaultEvent("migrate", 0.5, (1, 1))]),
+                     t_cut=T_CUT, fused=True)
+
+
 def test_oracle_self_compare_is_clean():
     golden = run_schedule(FaultSchedule(seed=55), t_cut=T_CUT)
     report = compare(golden, golden)

@@ -640,12 +640,12 @@ def _query_engine(query, seed):
 
 
 @pytest.mark.parametrize("query,kind", [("q5", "sum"), ("q7", "max"),
-                                        ("ysb", "read")])
+                                        ("ysb", "read"), ("q11", "sum")])
 def test_value_shadow_is_the_pool_in_query_runs(query, kind):
     """Small fused runs of windowed counts with evictions (q5), a
-    windowed max (q7) and the read-only join (YSB): before and after
-    every device batch, and after the drain, the value shadow equals
-    the pool at every occupied slot."""
+    windowed max (q7), the read-only join (YSB) and session counts with
+    merges (q11): before and after every device batch, and after the
+    drain, the value shadow equals the pool at every occupied slot."""
     from repro.streaming.engine import SourceOp
     eng = _query_engine(query, 11)
     op = eng.operators["stateful"]
@@ -688,6 +688,25 @@ def test_fusedplane_flush_and_export_roundtrip():
     assert plane.lookup("a", 5.0) == 1 and plane.lookup("b", 5.0) == 2
 
 
+def test_pool_read_lands_queued_drops_before_admissions():
+    """A slot a drop freed and a new key took in one sync interval holds
+    a queued drop and a queued admission.  A pool read (``flush_dirty``,
+    the checkpoint and drain path) lands them in that order, as a step's
+    sync does: the admitted key survives on the device."""
+    plane = FusedPlane(2 * 8, 8, count_spec(), batch=4)
+    plane.insert("a", 1, 1.0, dirty=True)
+    plane.insert("x", 5, 1.0)
+    plane._sync()
+    slot = plane._slot_by_key["a"]
+    plane.drop("a")
+    plane.insert("b", 7, 2.0, dirty=True)
+    assert plane._slot_by_key["b"] == slot
+    assert [e.key for e in plane.flush_dirty()] == ["b"]
+    res = plane.batch_step([Lane("b", 3.0, (0.0,), True, False, None)])
+    assert bool(res.hit[0]) and plane.decode_lane(res, 0) == 7
+    assert_shadow_is_pool(plane)
+
+
 @pytest.mark.parametrize("fused", [False, True])
 def test_fetch_completion_never_rolls_back_a_resident_entry(fused):
     """A fetch that lands after its key became resident again (served
@@ -709,12 +728,12 @@ def test_fetch_completion_never_rolls_back_a_resident_entry(fused):
     assert op.caches[0].flush_dirty()[0].state == 6
 
 
-@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign"])
+@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign", "q11"])
 def test_one_transfer_each_way_per_device_call_in_query_runs(query):
     """Small fused runs of each deployment the benchmark runs (q5's
     windowed count, YSB's read join, YSB as published with its campaign
-    count): every plane hands the device one array per call and reads
-    one back per batch step."""
+    count, q11's session count): every plane hands the device one array
+    per call and reads one back per batch step."""
     eng = _query_engine(query, 13)
     planes = [c for op in eng.operators.values()
               if isinstance(op, StatefulOp) for c in op.caches
@@ -849,7 +868,7 @@ def test_fire_and_late_lanes_decode_the_device_values(fire, late):
     assert_shadow_is_pool(plane)
 
 
-@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign"])
+@pytest.mark.parametrize("query", ["q5", "ysb", "ysb-campaign", "q11"])
 def test_host_predicts_the_device_hits_in_query_runs(query):
     """Every step of each deployment the benchmark runs settles, and so
     has its device hits, slots and tallies held to the host directory's.  q5's plain updates defer; every lane of the
@@ -901,3 +920,49 @@ def test_admissions_that_never_land_are_counted_not_fatal(monkeypatch):
     assert sum(p.hit_mismatches for p in planes) > 0
     assert all(p.deferred + p.forced_settles == p.calls["fused_step"]
                for p in planes)
+
+
+# ------------------------------------------ session windows (DESIGN.md §15)
+def test_session_merge_steps_run_on_the_host_between_batches():
+    """Fused q11: a merge's drain and absorb never ride a device lane —
+    they run through the plane's host API at a batch boundary — a fire
+    and an update of one pane never share a batch, and the session
+    counters, the merge settles and the fold and merge spans reach the
+    registry."""
+    from repro.streaming.sessions import _MergeAbsorb, _MergeDrain
+    eng = _query_engine("q11", 23)
+    eng.enable_spans()
+    op = eng.operators["stateful"]
+    plane = op.caches[0]
+    batches, step = [], plane.batch_step
+
+    def recording(lanes):
+        batches.append(list(lanes))
+        return step(lanes)
+    plane.batch_step = recording
+    eng.run(duration=2.0)
+    assert op.merge_drains > 0 and op.merge_absorbs == op.merge_drains
+    for lanes in batches:
+        assert not any(isinstance(ln.tup.payload, (_MergeDrain,
+                                                   _MergeAbsorb))
+                       for ln in lanes)
+        fires = {ln.key for ln in lanes if ln.fire}
+        assert not fires & {ln.key for ln in lanes if not ln.fire}
+    assert 0 < op.merge_settles <= op.merge_drains + op.merge_absorbs
+    assert op.merge_settles <= plane.forced_settles
+    eng._sync_registry()
+    reg = eng.registry.snapshot()
+    for k, attr in (("created", "sessions_created"),
+                    ("merged", "sessions_merged"),
+                    ("reopened", "sessions_reopened"),
+                    ("fires_superseded", "fires_superseded"),
+                    ("fires_absorbed", "fires_absorbed"),
+                    ("held", "merge_holds"),
+                    ("merge_settles", "merge_settles")):
+        assert reg[f"engine.stateful.sessions.{k}"] == getattr(op, attr)
+    assert reg["engine.sess_lookahead.sessions.rehints"] == \
+        eng.operators["sess_lookahead"].rehints > 0
+    spans = eng.spans.snapshot()["spans"]
+    assert spans["stream.stateful.session_fold"][0] > 0
+    assert spans["stream.stateful.merge"][0] == \
+        op.merge_drains + op.merge_absorbs

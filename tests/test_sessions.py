@@ -15,8 +15,10 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
-from repro.streaming.backend import IN_MEMORY
+from repro.streaming.backend import IN_MEMORY, LOCAL_NVME
 from repro.streaming.engine import Engine, SinkOp, SourceOp
+from repro.streaming.events import Tuple_, Watermark, WindowKey
+from repro.streaming.fused import FusedSpec
 from repro.streaming.nexmark import NexmarkConfig, build_query
 from repro.streaming.sessions import (SessionWindowAssigner,
                                       SessionWindowedOp)
@@ -134,12 +136,15 @@ def test_fold_predating_tuple_creates_new_survivor():
 
 
 def test_session_op_rejects_fused_plane():
+    """Session panes aggregate: a fused plane whose spec only reads
+    (the join's) has no update to fold a bid into."""
     eng = Engine()
     with pytest.raises(ValueError):
         SessionWindowedOp(
             eng, "s", 1, SessionWindowAssigner(1.0),
             lambda t, a: (a or 0) + 1, lambda *a: None,
-            IN_MEMORY, 10_000, fused=object(), state_size=100)
+            IN_MEMORY, 10_000, fused=FusedSpec(kind="read"),
+            policy="tac", state_size=100)
 
 
 # ------------------------------------------------- engine-level pipelines
@@ -280,3 +285,213 @@ def test_q11_requires_out_of_orderness():
     cfg = NexmarkConfig(rate=1000, oo_bound=0.0)
     with pytest.raises(ValueError):
         build_query("q11", "tac", "prefetch", cfg)
+
+
+# --------------------------------------- the fused plane (DESIGN.md §15)
+def _count_spec():
+    return FusedSpec(kind="sum", width=1, weight_of=lambda tup: 1.0,
+                     encode=lambda s: None if s is None else [float(s)],
+                     decode=lambda v: int(round(float(v[0]))))
+
+
+# the session counters that are registry decisions, made in input order
+# (merge holds and settles depend on when fetches land)
+SESSION_COUNTERS = ("sessions_created", "sessions_merged",
+                    "sessions_reopened", "fires_superseded",
+                    "fires_absorbed", "merge_drains", "merge_absorbs",
+                    "fires", "late_dropped", "panes_purged")
+
+
+def _observed(op, got):
+    """Sink results, final keyed state and session counters of a run."""
+    state = {}
+    for sub in range(op.parallelism):
+        for e in op.caches[sub].flush_dirty():
+            op.backends[sub].write(e.key, e.state, op.state_size)
+        state.update({k: v for k, v in op.backends[sub].data.items()
+                      if v is not None})
+    m = op.extra_metrics()
+    return sorted(got), state, {k: m[k] for k in SESSION_COUNTERS}
+
+
+def _run_q11(fused, gap, seed=11):
+    """NEXMark q11 live at 2,000 events/s over 2 subtasks, 48 slots a
+    subtask (panes evict and park), stream cut at gap + 2.5 s, then a
+    final watermark fires every session and one more purges them."""
+    cfg = NexmarkConfig(rate=2000.0, oo_bound=0.3, seed=seed,
+                        watermark_interval=0.05)
+    eng = build_query("q11", "tac", "prefetch", cfg, cache_entries=48,
+                      parallelism=2, source_parallelism=1, io_workers=4,
+                      buffer_timeout=0.002, session_gap=gap,
+                      allowed_lateness=1.0, fused=fused, fused_batch=32)
+    src, op = eng.operators["source"], eng.operators["stateful"]
+    t_cut = gap + 2.5
+    inner = src.gen
+    src.gen = lambda now: None if now >= t_cut else inner(now)
+    sink, got = eng.operators["sink"], []
+    process = sink.process
+    sink.process = lambda sub, tup: (
+        got.append((tup.ts, tup.key, tup.payload, tup.ingest_t)),
+        process(sub, tup))[1]
+    eng.run(duration=t_cut + 0.5)
+    for wm in (t_cut + gap + 5.0, t_cut + gap + 10.0):
+        src.wm[0] = wm
+        src.emit_watermark(0, wm)
+        eng.sim.run_until(eng.sim.t + 1.0)
+    src.stopped = True
+    eng.sim.run_until(eng.sim.t + 1.0)
+    return op, _observed(op, got)
+
+
+@pytest.mark.parametrize("gap", [1.0, 10.0])
+def test_fused_q11_equals_interpreted(gap):
+    """The same seed through both planes: the same sink results, final
+    keyed state and session counters, with panes evicted, parked,
+    merged and re-opened on the way."""
+    op_i, interp = _run_q11(False, gap)
+    op_f, fused = _run_q11(True, gap)
+    assert fused[0] == interp[0]
+    assert fused[1] == interp[1]
+    assert fused[2] == interp[2]
+    c = fused[2]
+    assert c["fires"] > 100 and c["sessions_merged"] > 0
+    assert c["panes_purged"] > 0
+    plane = op_f.caches[0]
+    assert plane.evictions > 0 and plane.calls["fused_step"] > 10
+    assert plane.hit_mismatches == 0
+    # a session fire carries its end as ingest_t (latency_from_end)
+    assert all(ingest == ts for ts, _, _, ingest in fused[0])
+
+
+def _session_op(eng, fused, slots, gap=1.0, lateness=1.0):
+    kw = dict(policy="tac", mode="async", cache_capacity=slots * 64,
+              state_size=64, io_workers=2, allowed_lateness=lateness,
+              late_policy="update", latency_from_end=True)
+    if fused:
+        kw.update(fused=_count_spec(), fused_batch=8)
+    op = eng.add(SessionWindowedOp(
+        eng, "win", 1, SessionWindowAssigner(gap),
+        agg_fn=lambda tup, acc: (acc or 0) + 1,
+        emit_fn=lambda key, wid, end, acc: ("session", key, wid, acc),
+        merge_fn=lambda a, b: (a or 0) + (b or 0),
+        backend_model=LOCAL_NVME, **kw))
+    sink = eng.add(_CollectSink(eng, "sink", 1))
+    eng.connect(op, sink)
+    return op, sink
+
+
+def _drive(fused, steps, slots=3):
+    """Hand-built input straight into one session operator, quiescing
+    after each step: ``("t", [(key, ts), ...])`` delivers tuples, ``("w",
+    wm)`` a watermark, ``("tw", [...], wm)`` both in one batch, so the
+    watermark queues behind the tuples' merge steps, and ``("wt", wm,
+    [...])`` the watermark with tuples queued behind it."""
+    eng = Engine()
+    op, sink = _session_op(eng, fused, slots)
+    got, emit = [], op.emit
+
+    def recording(sub, msg):
+        got.append((msg.ts, msg.key, msg.payload, msg.ingest_t))
+        return emit(sub, msg)
+    op.emit = recording
+    t, probes = 0.0, []
+    for step in steps:
+        if step[0] == "probe":             # which panes are resident
+            probes.append({(k, w): op.caches[0].contains(WindowKey(k, w))
+                           for k, w in step[1]})
+            continue
+        kind, wm = step[0], None
+        tuples = {"t": step[1], "tw": step[1], "wt": step[-1]}.get(kind, ())
+        if kind != "t":
+            wm = step[-1] if kind == "tw" else step[1]
+        batch = [Tuple_(ts, k, None, 64, 0.0) for k, ts in tuples]
+        if wm is not None:
+            batch.insert(len(batch) if kind == "tw" else 0, Watermark(wm))
+        op.deliver_batch(0, batch)
+        t += 0.05
+        eng.sim.run_until(t)
+    eng.sim.run_until(t + 1.0)
+    return op, _observed(op, got), probes
+
+
+_A = SessionWindowAssigner(1.0)
+# key 0: session A at 0.0 and session B at 1.5 ([0, 1) and [1.5, 2.5)),
+# then a bid at 0.8 bridges them: A survives, B is drained into it
+# three panes of later deadlines, of three slots: A and B are evicted
+_FILL = [(1, 1.6), (2, 1.7), (3, 1.8)]
+MERGE_CASES = {
+    "absorbed_resident": [
+        ("t", [(0, 0.0), (0, 1.5)]),
+        ("probe", [(0, _A.wid_of(1.5))]),
+        ("t", [(0, 0.8)]), ("w", 3.0), ("w", 10.0)],
+    "absorbed_parked": [
+        ("t", [(0, 0.0), (0, 1.5)]), ("t", _FILL),
+        ("probe", [(0, _A.wid_of(1.5))]),
+        ("t", [(0, 0.8)]), ("w", 3.0), ("w", 10.0)],
+    "fire_held": [
+        ("t", [(0, 0.0), (0, 1.5)]), ("t", _FILL),
+        ("probe", [(0, _A.wid_of(1.5))]),
+        ("tw", [(0, 0.8)], 3.0), ("w", 10.0)],
+    "late_reopen": [
+        ("t", [(0, 0.0), (0, 1.5)]), ("probe", [(0, _A.wid_of(1.5))]),
+        ("t", [(0, 0.8)]), ("w", 3.0),
+        ("t", [(0, 2.0)]), ("w", 3.1), ("w", 10.0)],
+    # the late bid already queued behind the watermark: the fire still
+    # counts what came before its watermark, and the bid re-fires
+    "reopen_behind_fire": [
+        ("t", [(0, 0.0), (0, 1.5)]), ("probe", [(0, _A.wid_of(1.5))]),
+        ("t", [(0, 0.8)]), ("wt", 3.0, [(0, 2.0)]), ("w", 3.1),
+        ("w", 10.0)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(MERGE_CASES))
+def test_fused_merge_cases_equal_interpreted(case):
+    """Each merge case, forced by hand, gives the same emits, state and
+    session counters on both planes, and the counts are exact: A's bid,
+    B's and the bridge's in one session fire, and a late bid re-opens
+    it for a re-fire."""
+    runs = [_drive(fused, MERGE_CASES[case]) for fused in (False, True)]
+    (op_i, obs_i, probes_i), (op_f, obs_f, probes_f) = runs
+    assert obs_f == obs_i
+    assert probes_f == probes_i
+    got, state, ctr = obs_f
+    wid = _A.wid_of(0.0)
+    fires = [(ts, p[3]) for ts, k, p, _ in got if k == 0 and p[2] == wid]
+    assert ctr["sessions_merged"] == 1 and ctr["merge_absorbs"] == 1
+    assert not any(p[2] == _A.wid_of(1.5) for _, k, p, _ in got if k == 0)
+    reopens = case in ("late_reopen", "reopen_behind_fire")
+    if reopens:
+        assert fires == [(2.5, 3), (3.0, 4)]
+        assert ctr["sessions_reopened"] == 1
+    else:
+        assert fires == [(2.5, 3)]
+    resident = probes_f[0][(0, _A.wid_of(1.5))]
+    assert resident == (case == "absorbed_resident" or reopens)
+    for op in (op_i, op_f):
+        # the held fire waited for the drain's fetch, then read all three
+        assert (op.merge_holds > 0) == (case == "fire_held")
+        assert not op.held[0] and not op.pending[0]
+    assert all(ingest == ts for ts, _, _, ingest in got)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["interp", "fused"])
+@pytest.mark.parametrize("from_end", [True, False], ids=["end", "fire"])
+def test_session_fires_carry_their_end_as_ingest_t(fused, from_end):
+    """With ``latency_from_end`` a fire's ``ingest_t`` is its session's
+    end, so sink latency runs from the end to delivery; without it, the
+    simulated time the fire was scheduled."""
+    eng = Engine()
+    op, _ = _session_op(eng, fused, 8)
+    op.latency_from_end = from_end
+    got, emit = [], op.emit
+    op.emit = lambda sub, msg: (got.append(msg), emit(sub, msg))[1]
+    op.deliver_batch(0, [Tuple_(0.0, 0, None, 64, 0.0),
+                         Tuple_(0.4, 0, None, 64, 0.0)])
+    eng.sim.run_until(0.5)
+    op.deliver_batch(0, [Watermark(2.0)])
+    eng.sim.run_until(1.0)
+    (msg,) = got
+    assert msg.payload == ("session", 0, _A.wid_of(0.0), 2)
+    assert msg.ts == pytest.approx(1.4)
+    assert msg.ingest_t == pytest.approx(1.4 if from_end else 0.5)

@@ -24,6 +24,7 @@ Three modes:
     legacy cumulative-only files.
 
     PYTHONPATH=src python tools/obs_report.py
+    PYTHONPATH=src python tools/obs_report.py --query q11 --fused
     PYTHONPATH=src python tools/obs_report.py --timeline --since 1.0
     PYTHONPATH=src python tools/obs_report.py --snapshot run.jsonl --grep prefetch
 """
@@ -127,6 +128,30 @@ def print_fused(fb: dict) -> None:
           f"directory; 0 in a sound run)")
 
 
+def print_sessions(m: dict) -> None:
+    """Session-window rollup (DESIGN.md §15): how often deadlines moved,
+    sessions merged and re-opened, and what the merges cost the fused
+    plane (held accesses, early settles)."""
+    if "stateful_sessions_created" not in m:
+        return
+    print("\nsession windows:")
+    for label, k, why in (
+            ("created", "sessions_created", ""),
+            ("merged", "sessions_merged", "absorbed into another session"),
+            ("reopened", "sessions_reopened", "late tuple, re-fired"),
+            ("fires superseded", "fires_superseded",
+             "deadline moved by a later tuple"),
+            ("fires absorbed", "fires_absorbed",
+             "merged away before firing"),
+            ("held", "merge_holds", "accesses behind absorbs in flight"),
+            ("merge settles", "merge_settles",
+             "merge steps that settled a fused step early")):
+        print(f"  {label:<16s} {m.get('stateful_' + k, 0):>8d}"
+              + (f"   ({why})" if why else ""))
+    print(f"  {'rehints':<16s} {m.get('sess_lookahead_rehints', 0):>8d}"
+          f"   (moved deadlines re-hinted by the lookahead)")
+
+
 def print_spans(spans: dict) -> None:
     """Host wall time by span (DESIGN.md §12), self time, largest first:
     which callback, drain, adjudication or device wait the host spends
@@ -168,6 +193,9 @@ def _build_smoke(args):
               hint_ts="deadline", fused=args.fused)
     if args.query == "q20":
         return build_query("q20", "tac", "prefetch", cfg, **kw)
+    if args.query == "q11":
+        return build_query("q11", "tac", "prefetch", cfg, session_gap=0.5,
+                           allowed_lateness=0.3, **kw)
     return build_query("q5", "tac", "prefetch", cfg, window_size=1.0,
                        window_slide=0.5, **kw)
 
@@ -189,6 +217,7 @@ def run_report(args) -> int:
     print_quality(m.get("stateful_hint_quality", {}),
                   m.get("stateful_evictions", {}))
     print_fused(m.get("stateful_fused", {}))
+    print_sessions(m)
     print_spans(m.get("spans", {}))
     if args.export:
         print(f"\nregistry snapshots appended to {args.export}")
@@ -331,16 +360,17 @@ def main() -> int:
     ap.add_argument("--interval", type=float, default=0.1,
                     help="with --timeline: interval width on the "
                          "logical clock (s)")
-    ap.add_argument("--query", choices=("q5", "q20"), default="q5",
-                    help="smoke pipeline to run (q5 sliding windows or "
-                         "q20 stateful filter-join)")
+    ap.add_argument("--query", choices=("q5", "q20", "q11"), default="q5",
+                    help="smoke pipeline to run (q5 sliding windows, "
+                         "q20 stateful filter-join or q11 sessions)")
     ap.add_argument("--duration", type=float, default=3.0)
     ap.add_argument("--warmup", type=float, default=1.5)
     ap.add_argument("--sample-every", type=int, default=16)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--fused", action="store_true",
-                    help="run the q5 smoke pipeline on the fused device "
-                         "hot path and report its batch-fill ratio")
+                    help="run the q5 or q11 smoke pipeline on the fused "
+                         "device hot path and report its batch-fill "
+                         "ratio")
     ap.add_argument("--export", metavar="FILE.jsonl",
                     help="also append registry snapshots during the run "
                          "(with --timeline: the timeline JSONL instead)")
